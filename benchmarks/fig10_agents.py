@@ -46,34 +46,23 @@ def dse_throughput(steps: int = 500, arch: str = "gpt3-13b") -> tuple[float, flo
 BACKEND_ROW_ORDER = ("reference", "jax-unfused", "jax")
 
 
-def backend_throughput(points: int = 32, n_requests: int = 256,
-                       repeats: int = 3) -> "list[dict] | None":
-    """Points/sec per simulation backend (reference / jax-unfused / jax)
-    evaluating one agent population of collective/network stacks over a
-    LARGE pipelined request-stream trace — the acceptance measurement for
-    the backend API and the fused-evaluation path.  All rows run through
-    ``CosmicEnv.step_batch`` (the PR-1 batched engine); the jax rows swap
-    the per-point heapq event loop for one shared-plan ``simulate_batch``
-    sweep, and the fused ``jax`` row additionally prices all durations
-    inside the same compiled call.  Each row carries the backend's
-    duration-pass vs compiled-sweep wall split (``last_timings``) so the
-    bottleneck claim stays measurable.  None when jax is unavailable."""
-    from repro.core.backends import backend_available, get_backend
+def backend_population(points: int = 32, n_requests: int = 256, seed: int = 0):
+    """The backend-throughput workload: ``n_requests`` Poisson requests of
+    qwen2-1.5b through disaggregated pools (256 give a ~26k-op pipelined
+    multi-wave trace) and a seeded ``points``-member population of
+    collective/network stacks.  The trace-shaping knobs are pinned, so the
+    whole population shares ONE scheduling plan.  Returns (scenario, cfgs)."""
     from repro.core.scenario import RequestStreamScenario
 
-    if not backend_available("jax"):
-        return None
-    # n_requests=256 Poisson requests through disaggregated pools -> a
-    # ~26k-op pipelined multi-wave trace; trace-shaping knobs are pinned so
-    # the whole population shares ONE scheduling plan
     scenario = RequestStreamScenario(n_requests=n_requests, seq=2048,
-                                     decode_tokens=64, rate_rps=32.0, seed=0)
+                                     decode_tokens=64, rate_rps=32.0,
+                                     seed=seed)
     pinned = dict(dp=8, sp=1, pp=1, weight_sharded=0,
                   topology=("ring", "fc", "ring", "switch"),
                   npus_per_dim=(4, 8, 4, 8),
                   prefill_frac=0.5, decode_batch=8, batch_window_ms=50.0,
                   max_inflight=2)
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(seed)
     algos = ("ring", "direct", "rhd", "dbt")
     cfgs = []
     for _ in range(points):
@@ -85,6 +74,27 @@ def backend_throughput(points: int = 32, n_requests: int = 256,
             multidim_coll=str(rng.choice(("baseline", "blueconnect"))),
             bw_per_dim=tuple(int(b) for b in
                              rng.choice(range(50, 501, 50), size=4))))
+    return scenario, cfgs
+
+
+def backend_throughput(points: int = 32, n_requests: int = 256,
+                       repeats: int = 3) -> "list[dict] | None":
+    """Points/sec per simulation backend (reference / jax-unfused / jax)
+    evaluating one agent population of collective/network stacks over a
+    LARGE pipelined request-stream trace (``backend_population``) — the
+    acceptance measurement for the backend API and the fused-evaluation
+    path.  All rows run through ``CosmicEnv.step_batch`` (the PR-1 batched
+    engine); the jax rows swap the per-point heapq event loop for one
+    shared-plan ``simulate_batch`` sweep, and the fused ``jax`` row
+    additionally prices all durations inside the same compiled call.  Each
+    row carries the backend's duration-pass vs compiled-sweep wall split
+    (``last_timings``) so the bottleneck claim stays measurable.  None when
+    jax is unavailable."""
+    from repro.core.backends import backend_available, get_backend
+
+    if not backend_available("jax"):
+        return None
+    scenario, cfgs = backend_population(points, n_requests)
     rows = []
     for backend in BACKEND_ROW_ORDER:
         env = make_env("qwen2-1.5b", "system2", scenario=scenario,

@@ -120,12 +120,6 @@ def _plan_parents(trace: Trace, plan: _SimPlan) -> np.ndarray:
     return parents
 
 
-def _x64():
-    """Double-precision tracing scoped to this backend's sweeps (the global
-    default stays untouched for the pallas/kernel code paths)."""
-    return jax.experimental.enable_x64()
-
-
 def _fused_eval(plan: _SimPlan):
     """The per-plan fused kernel: population duration tables in, per-op
     durations AND finish times out, one jit-compiled call.
@@ -208,7 +202,9 @@ class JaxBackend:
             plan, tables = plan_duration_tables(trace, calls)
             parents = plan.pack_memo.get("_parents_dev")
             t1 = time.perf_counter()
-            with _x64():
+            # double precision scoped to the sweep (the global default stays
+            # f32 for the model and kernel code paths)
+            with jax.enable_x64(True):
                 if parents is None:
                     # keep the static parent table resident on device — it
                     # is the same every batch and re-uploading it costs
@@ -225,7 +221,7 @@ class JaxBackend:
             parents = _plan_parents(trace, plan)
             dur = np.asarray([d for _, d in plans_durs], dtype=np.float64)
             t1 = time.perf_counter()
-            with _x64():
+            with jax.enable_x64(True):
                 finish = np.asarray(_sweep_population(
                     jnp.asarray(dur.T), jnp.asarray(parents)))[:plan.n_ops].T
         t2 = time.perf_counter()

@@ -200,9 +200,19 @@ def _multidim_collective_time_impl(kind: str, size_bytes: float, net: Network,
     c = max(chunks, 1)
     if mode == "blueconnect":
         # concurrent per-dim schedules on disjoint chunk shards
-        return max(phases) + (sum(phases) - max(phases)) / c
+        return max(phases) + (_sum_in_order(phases) - max(phases)) / c
     # hierarchical with chunk pipelining between consecutive phases
-    return sum(p / c for p in phases) + (c - 1) / c * max(phases)
+    return _sum_in_order(p / c for p in phases) + (c - 1) / c * max(phases)
+
+
+def _sum_in_order(xs) -> float:
+    """Plain left-to-right float sum.  ``sum()`` compensates its rounding
+    since Python 3.12; the vectorized evaluator's unrolled adds do not, and
+    the two paths must agree bit for bit."""
+    total = 0.0
+    for x in xs:
+        total += x
+    return total
 
 
 _multidim_collective_time_cached = \
@@ -213,12 +223,25 @@ _multidim_collective_time_cached = \
 # Vectorized evaluator: the same model over arrays of integer ids
 # ---------------------------------------------------------------------------
 
+def _bit_length_i32(m):
+    """Bit length of a jnp int32 array of values >= 1 — what the exponent of
+    ``np.frexp(m)`` is, computed in integers (the TPU cannot lower ``frexp``
+    on f64: its x64 rewrite does not cover the bitcast)."""
+    from jax import lax
+
+    return 32 - lax.clz(m)
+
+
 def _vec_ceil_log2(n, xp):
     """ceil(log2(n)) for float arrays of integers, exactly: the exponent of
     frexp(n - 1) is bit_length(n - 1), with no libm rounding to worry about.
     Returns 1 where n <= 2 (callers only consume lg through congestion /
     rhd-dbt step counts, which are guarded there)."""
-    _, e = xp.frexp(xp.maximum(n - 1.0, 1.0))
+    m = xp.maximum(n - 1.0, 1.0)
+    if xp is np:
+        _, e = np.frexp(m)
+    else:
+        e = _bit_length_i32(m.astype(xp.int32))
     return xp.maximum(e.astype(xp.float64), 1.0)
 
 
@@ -280,7 +303,7 @@ def multidim_collective_time_vec(kind_id, size_bytes, npus, bw, latency_us,
     the internal derivation handles that, host-built tables must too.
 
     Reductions over the dim axis are unrolled so the accumulation order
-    matches the scalar path's active-dims-in-order ``sum()``/``max()`` —
+    matches the scalar path's active-dims-in-order sum and ``max()`` —
     with a host-exact ``scale`` the numpy evaluation is bit-identical to
     the (uncached) scalar model."""
     n = xp.asarray(npus, dtype=xp.float64)

@@ -320,7 +320,9 @@ class CosmicEnv:
             # sweep (and pay a per-worker jit compile).
             jobs = [self.scenario.sim_job(self.context(c)) for c in cfgs]
             return run_sim_jobs(jobs, backend)
-        if workers > 1 and len(cfgs) > 1:
+        if workers > 1 and len(cfgs) > 1 and self.backend == "reference":
+            # only the pure-numpy backend fans out: every worker of a jax
+            # backend would load jax and try to take the same accelerator
             pool = self._get_executor(workers)
             chunk = max(1, len(cfgs) // (self._executor_workers * 2))
             flags = itertools.repeat(caches_enabled())

@@ -184,6 +184,11 @@ class StudySpec:
         if self.backend not in BACKEND_REGISTRY:
             raise ValueError(f"unknown simulation backend {self.backend!r}; "
                              f"known: {sorted(BACKEND_REGISTRY)}")
+        if self.workers > 1 and self.backend != "reference":
+            raise ValueError(
+                f"workers={self.workers} needs the reference backend: a "
+                f"{self.backend!r} worker pool would put one jax process per "
+                f"worker on the same accelerator")
         if not self.agents:
             raise ValueError("agents grid is empty")
         if not self.seeds:

@@ -1,10 +1,11 @@
 """jit'd public wrappers around the Pallas kernels.
 
 These adapt model-layout tensors (B, S, H, hd / GQA groups) to kernel
-layouts (heads folded into batch, padded to block multiples) and expose a
-``use_pallas`` switch: models default to the pure-jnp path (the dry-run
-compiles on the CPU backend where TPU-Pallas cannot lower); on TPU the
-kernels drop in via these wrappers.
+layouts (heads folded into batch, padded to block multiples).  No model
+calls them yet: the models run their pure-jnp paths (``models.attention``,
+``models.mamba``, ``models.layers.rmsnorm``), and the kernels are checked
+against those paths in ``tests/test_kernels.py`` and compiled for the TPU
+in ``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 
@@ -61,13 +62,21 @@ def ssd(x, dt, a, b, c, *, chunk: int = 128, interpret: bool | None = None):
 
 @functools.partial(jax.jit, static_argnames=("eps", "interpret"))
 def fused_rmsnorm(x, w, *, eps: float = 1e-5, interpret: bool | None = None):
-    """x: (..., d) any leading shape."""
+    """x: (..., d) any leading shape.
+
+    Rows are zero-padded to a multiple of 8 (the TPU's sublane tile: a
+    block of fewer rows than that is refused unless it spans the whole
+    array), and the block is the largest power of two up to 128 dividing
+    the padded count.  Padded rows normalize to 0 and are sliced off."""
     shape = x.shape
     rows = math.prod(shape[:-1])
     d = shape[-1]
+    padded = -(-rows // 8) * 8
     block = 128
-    while rows % block and block > 1:
+    while padded % block:
         block //= 2
-    out = rmsnorm_kernel(x.reshape(rows, d), w, eps=eps, block_rows=block,
-                         interpret=interpret)
-    return out.reshape(shape)
+    x2 = x.reshape(rows, d)
+    if padded != rows:
+        x2 = jnp.pad(x2, ((0, padded - rows), (0, 0)))
+    out = rmsnorm_kernel(x2, w, eps=eps, block_rows=block, interpret=interpret)
+    return out[:rows].reshape(shape)

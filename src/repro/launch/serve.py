@@ -12,6 +12,7 @@ import numpy as np
 
 from repro.configs import ARCHS, get_arch, reduced
 from repro.models import model as M
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serve.engine import Engine
 
 
@@ -26,6 +27,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    use_compile_cache()
     spec = get_arch(args.arch)
     if args.reduced:
         spec = reduced(spec)

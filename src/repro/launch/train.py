@@ -11,19 +11,23 @@ fault-tolerance drills.
 from __future__ import annotations
 
 import argparse
-import json
+import contextlib
+import functools
 import time
+from dataclasses import dataclass
 from pathlib import Path
+from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.sharding import NamedSharding
 
 from repro.configs import ARCHS, get_arch, reduced
 from repro.ckpt.checkpoint import AsyncCheckpointer, latest_step, restore
 from repro.data.pipeline import DataConfig, Prefetcher, SyntheticLM
 from repro.launch.mesh import make_mesh
-from repro.parallel.sharding import NULL_PLAN, plan_for_mesh
+from repro.parallel.sharding import NULL_PLAN, plan_for_mesh, tree_shardings
+from repro.runtime.compile_cache import use_compile_cache
 from repro.runtime.fault import Heartbeat, StragglerMonitor
 from repro.train import optimizer as opt
 from repro.train.train_step import (RunConfig, batch_axes, init_train_state,
@@ -31,24 +35,40 @@ from repro.train.train_step import (RunConfig, batch_axes, init_train_state,
 
 
 def build(spec, mesh, cfg: RunConfig, seed: int = 0):
+    """The jitted step, the initial state and the batch shardings for
+    ``mesh`` (None: the default device, and shardings None).  The state is
+    initialised inside one jitted call whose outputs are already sharded, so
+    no device ever holds more than its own shards of it."""
     plan = plan_for_mesh(mesh) if mesh is not None else NULL_PLAN
     step_fn = make_train_step(spec, plan, cfg)
-    state = init_train_state(jax.random.PRNGKey(seed), spec, cfg)
+    init = functools.partial(init_train_state, spec=spec, cfg=cfg)
+    rng = jax.random.PRNGKey(seed)
+    state_sh = batch_sh = None
     if mesh is not None:
-        from repro.parallel.sharding import tree_shardings
         ax = train_state_axes(spec, cfg)
-        specs = jax.tree.map(lambda a, s: plan.spec(a, np.shape(s)), ax, state,
+        specs = jax.tree.map(lambda a, s: plan.spec(a, s.shape), ax,
+                             jax.eval_shape(init, rng),
                              is_leaf=lambda x: isinstance(x, tuple) and all(
                                  isinstance(e, (str, type(None))) for e in x))
-        sh = tree_shardings(mesh, specs)
-        state = jax.device_put(state, sh)
-        jit_step = jax.jit(step_fn, donate_argnums=(0,))
-    else:
-        jit_step = jax.jit(step_fn, donate_argnums=(0,))
-    return jit_step, state
+        state_sh = tree_shardings(mesh, specs)
+        batch_sh = {k: NamedSharding(mesh, plan.spec(a))
+                    for k, a in batch_axes(spec).items()}
+    state = jax.jit(init, out_shardings=state_sh)(rng)
+    return jax.jit(step_fn, donate_argnums=(0,)), state, batch_sh
 
 
-def train_loop(args, spec, fail_at: int | None = None) -> int:
+@dataclass
+class TrainRun:
+    """What ``train_loop`` leaves behind: the step it stopped at, the loss
+    and the wall seconds (the first includes compilation) of every step it
+    ran, and the final state."""
+    final: int
+    losses: list[float]
+    step_s: list[float]
+    state: Any
+
+
+def train_loop(args, spec, fail_at: int | None = None) -> TrainRun:
     cfg = RunConfig(
         compute_dtype=jnp.bfloat16 if args.bf16 else jnp.float32,
         param_dtype=jnp.bfloat16 if args.bf16 else jnp.float32,
@@ -61,52 +81,57 @@ def train_loop(args, spec, fail_at: int | None = None) -> int:
         names = ("data", "model")[: len(shape)]
         mesh = make_mesh(shape, names)
 
-    jit_step, state = build(spec, mesh, cfg, args.seed)
+    # the model's sharding constraints name mesh axes: they resolve
+    # against the mesh in scope while the step is traced
+    with jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext():
+        jit_step, state, batch_sh = build(spec, mesh, cfg, args.seed)
 
-    ckpt = AsyncCheckpointer(args.ckpt_dir, keep=2) if args.ckpt_dir else None
-    start = 0
-    if ckpt and latest_step(args.ckpt_dir) is not None:
-        state, start = restore(args.ckpt_dir, state)
-        print(f"[train] resumed from step {start}", flush=True)
+        ckpt = AsyncCheckpointer(args.ckpt_dir, keep=2) if args.ckpt_dir else None
+        start = 0
+        if ckpt and latest_step(args.ckpt_dir) is not None:
+            state, start = restore(args.ckpt_dir, state)
+            print(f"[train] resumed from step {start}", flush=True)
 
-    data = SyntheticLM(spec, DataConfig(args.batch, args.seq, seed=args.seed))
-    prefetch = Prefetcher(data, start_step=start, depth=2)
-    hb = Heartbeat(Path(args.ckpt_dir or "/tmp") / "heartbeat.json") if args.ckpt_dir else None
-    straggler = StragglerMonitor(k_sigma=args.straggler_sigma)
+        data = SyntheticLM(spec, DataConfig(args.batch, args.seq, seed=args.seed))
+        prefetch = Prefetcher(data, start_step=start, depth=2)
+        hb = Heartbeat(Path(args.ckpt_dir) / "heartbeat.json") if args.ckpt_dir else None
+        straggler = StragglerMonitor(k_sigma=args.straggler_sigma)
 
-    losses = []
-    it = iter(prefetch)
-    try:
-        for step, batch in it:
-            if step >= args.steps:
-                break
-            if fail_at is not None and step == fail_at:
-                raise RuntimeError(f"injected failure at step {step}")
-            t0 = time.time()
-            state, metrics = jit_step(state, batch)
-            loss = float(metrics["loss"])
-            losses.append(loss)
-            dt = time.time() - t0
-            if straggler.observe(step, dt):
-                print(f"[straggler] step {step} took {dt:.3f}s "
-                      f"(mean {straggler.mean:.3f}s) — mitigation hook fired", flush=True)
-            if hb:
-                hb.beat(step)
-            if ckpt and (step + 1) % args.ckpt_every == 0:
-                ckpt.save(state, step + 1)
-            if step % args.log_every == 0:
-                print(f"[train] step {step} loss {loss:.4f} "
-                      f"({dt*1e3:.0f} ms)", flush=True)
-        final = min(args.steps, step + 1)
-    finally:
-        prefetch.close()
-    if ckpt:
-        ckpt.save(state, final, block=True)
-    print(f"[train] done at step {final}; loss {losses[0]:.4f} -> {losses[-1]:.4f}", flush=True)
-    return final
+        losses: list[float] = []
+        step_s: list[float] = []
+        it = iter(prefetch)
+        try:
+            for step, batch in it:
+                if step >= args.steps:
+                    break
+                if fail_at is not None and step == fail_at:
+                    raise RuntimeError(f"injected failure at step {step}")
+                t0 = time.time()
+                state, metrics = jit_step(state, jax.device_put(batch, batch_sh))
+                loss = float(metrics["loss"])
+                losses.append(loss)
+                dt = time.time() - t0
+                step_s.append(dt)
+                if straggler.observe(step, dt):
+                    print(f"[straggler] step {step} took {dt:.3f}s "
+                          f"(mean {straggler.mean:.3f}s) — mitigation hook fired", flush=True)
+                if hb:
+                    hb.beat(step)
+                if ckpt and (step + 1) % args.ckpt_every == 0:
+                    ckpt.save(state, step + 1)
+                if step % args.log_every == 0:
+                    print(f"[train] step {step} loss {loss:.4f} "
+                          f"({dt*1e3:.0f} ms)", flush=True)
+            final = min(args.steps, step + 1)
+        finally:
+            prefetch.close()
+        if ckpt:
+            ckpt.save(state, final, block=True)
+        print(f"[train] done at step {final}; loss {losses[0]:.4f} -> {losses[-1]:.4f}", flush=True)
+        return TrainRun(final, losses, step_s, state)
 
 
-def main() -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b", choices=sorted(ARCHS))
     ap.add_argument("--reduced", action="store_true",
@@ -127,8 +152,12 @@ def main() -> None:
     ap.add_argument("--straggler-sigma", type=float, default=3.0)
     ap.add_argument("--fail-at", type=int, default=None,
                     help="inject a failure at this step (fault drill)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def main() -> None:
+    args = parse_args()
+    use_compile_cache()
     spec = get_arch(args.arch)
     if args.reduced:
         spec = reduced(spec)

@@ -39,9 +39,11 @@ class Engine:
         self.plan = plan
         self.max_len = max_len
         self.dtype = dtype
-        self._prefill = jax.jit(
+        # the two compiled steps ``generate`` drives: prompt pass -> (last
+        # logits, filled caches), and one cached token -> (logits, caches)
+        self.prefill = jax.jit(
             lambda p, t, c: M.prefill(p, t, c, spec, plan, compute_dtype=dtype))
-        self._decode = jax.jit(
+        self.decode = jax.jit(
             lambda p, c, t, pos: M.decode_step(p, c, t, pos, spec, plan,
                                                compute_dtype=dtype))
 
@@ -54,7 +56,7 @@ class Engine:
         caches = M.init_caches(self.spec, b, self.max_len, dtype=self.dtype)
 
         t0 = time.time()
-        logits, caches = self._prefill(self.params, jnp.asarray(prompts), caches)
+        logits, caches = self.prefill(self.params, jnp.asarray(prompts), caches)
         logits.block_until_ready()
         stats.prefill_s = time.time() - t0
 
@@ -68,7 +70,7 @@ class Engine:
             else:
                 tok = jnp.argmax(logits, axis=-1)
             out[:, i] = np.asarray(tok)
-            logits, caches = self._decode(self.params, caches, tok.astype(jnp.int32),
+            logits, caches = self.decode(self.params, caches, tok.astype(jnp.int32),
                                           jnp.asarray(s + i, jnp.int32))
         jax.block_until_ready(logits)
         stats.decode_s = time.time() - t0

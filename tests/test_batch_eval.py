@@ -2,6 +2,8 @@
 bit-identical memoization layers, and process-pool consistency."""
 from __future__ import annotations
 
+from dataclasses import dataclass
+
 import numpy as np
 import pytest
 
@@ -14,6 +16,7 @@ from repro.core.compute import SYSTEM_2_DEVICE
 from repro.core.dse import run_search
 from repro.core.env import CosmicEnv
 from repro.core.psa import paper_psa
+from repro.core.scenario import TrainScenario
 from repro.core.space import DesignSpace
 from repro.core.topology import build_network, system_2
 from repro.core.workload import Parallelism, _generate_trace_impl, generate_trace
@@ -145,6 +148,45 @@ def test_step_batch_process_pool_matches_serial(clear_dse_caches):
         assert (a.reward, a.latency_ms, a.valid) == (b.reward, b.latency_ms, b.valid)
     # history recorded in input order
     assert [r.config for r in pool_env.history] == cfgs
+
+
+@dataclass(frozen=True)
+class _NoJobScenario:
+    """A scenario without ``sim_job``: the env can only evaluate it point by
+    point, so ``workers > 1`` reaches the process-pool branch."""
+    inner: TrainScenario
+    name: str = "no-job"
+
+    def psa_params(self):
+        return self.inner.psa_params()
+
+    def psa_constraints(self, n_npus):
+        return self.inner.psa_constraints(n_npus)
+
+    def traces(self, ctx):
+        return self.inner.traces(ctx)
+
+    def evaluate(self, ctx):
+        return self.inner.evaluate(ctx)
+
+
+def test_jax_backend_never_starts_a_worker(clear_dse_caches):
+    """One process per accelerator: a jax-backend env asked for workers
+    evaluates in its own process and never creates the pool."""
+    space = DesignSpace(paper_psa(1024))
+    rng = np.random.default_rng(5)
+    cfgs = [space.sample(rng) for _ in range(4)]
+    serial = [_env().evaluate_config(c) for c in cfgs]
+    with CosmicEnv(spec=ARCHS["gpt3-13b"], n_npus=1024,
+                   device=SYSTEM_2_DEVICE,
+                   scenario=_NoJobScenario(TrainScenario(1024, 2048)),
+                   backend="jax") as env:
+        got = env.step_batch(cfgs, workers=2)
+        assert env._executor is None
+    for a, b in zip(got, serial):
+        assert a.valid == b.valid
+        if b.valid:
+            assert abs(a.latency_ms - b.latency_ms) <= 1e-9 * b.latency_ms
 
 
 @pytest.mark.slow

@@ -103,6 +103,19 @@ def _pack_dims(carved, coll_algo):
     return npus, bw, lat, topo, algo
 
 
+def test_integer_bit_length_equals_frexp_exponent():
+    """The jnp ceil-log2 path counts bits in int32 (the TPU cannot lower
+    ``frexp`` on f64); it must equal numpy's frexp exponent everywhere."""
+    import jax.numpy as jnp
+
+    from repro.core.collectives import _bit_length_i32
+
+    n = np.arange(1, 2 ** 20 + 1, dtype=np.int64)
+    _, want = np.frexp(n.astype(np.float64))
+    got = np.asarray(_bit_length_i32(jnp.asarray(n, dtype=jnp.int32)))
+    np.testing.assert_array_equal(got, want)
+
+
 def test_multidim_vec_parity_random_sweep():
     """Randomized full sweep vs the scalar oracle: every collective kind,
     per-dim algo mix, both decomposition modes, chunk grid, over random

@@ -59,10 +59,7 @@ def test_stock_cost_analysis_undercounts_loops():
     x = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     ws = jax.ShapeDtypeStruct((16, 128, 128), jnp.float32)
     comp = jax.jit(scanned).lower(x, ws).compile()
-    ca = comp.cost_analysis()
-    if isinstance(ca, list):  # older jax returns one dict per device
-        ca = ca[0]
-    stock = ca["flops"]
+    stock = comp.cost_analysis()["flops"]
     ours = HloCostModel(comp.as_text()).analyze().flops
     assert ours > 10 * stock  # 16 iterations vs 1
 

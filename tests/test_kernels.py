@@ -107,7 +107,8 @@ def test_ssd_kernel_matches_model_path():
     np.testing.assert_allclose(np.asarray(hk), np.asarray(hm), rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("rows,d", [(128, 256), (64, 1024), (37 * 4, 512)])
+@pytest.mark.parametrize("rows,d", [(128, 256), (64, 1024), (37 * 4, 512),
+                                    (37, 512)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_rmsnorm_kernel(rows, d, dtype):
     x = (jax.random.normal(RNG, (rows, d)) * 3).astype(dtype)

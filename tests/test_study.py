@@ -60,6 +60,14 @@ def test_studyspec_json_roundtrip():
     assert _train_spec(workers=4).spec_hash() == _train_spec().spec_hash()
 
 
+def test_studyspec_refuses_a_worker_pool_on_a_jax_backend():
+    """Each pool worker would load jax and reach for the same accelerator."""
+    with pytest.raises(ValueError, match="needs the reference backend"):
+        _train_spec(workers=2, backend="jax")
+    assert _train_spec(workers=2).workers == 2
+    assert _train_spec(workers=1, backend="jax").backend == "jax"
+
+
 def test_studyspec_rejects_bad_names_at_spec_time():
     with pytest.raises(ValueError, match="unknown arch"):
         _train_spec(arch="not-a-model")
