@@ -49,6 +49,7 @@ from repro.core.systems import system_env  # noqa: E402
 from repro.data.pipeline import DataConfig, SyntheticLM  # noqa: E402
 from repro.launch.train import parse_args, train_loop  # noqa: E402
 from repro.models import model as M  # noqa: E402
+from repro.runtime import spans  # noqa: E402
 from repro.runtime.compile_cache import use_compile_cache  # noqa: E402
 from repro.serve.engine import Engine  # noqa: E402
 from repro.train.loss import cross_entropy  # noqa: E402
@@ -272,18 +273,26 @@ def say_cut(spec, args) -> None:
             "remat_none_needs_15.76GB")
 
 
-def steady_s(run) -> float:
-    return statistics.median(run.step_s[1:])
+def step_walls(run) -> list[float]:
+    """Wall seconds of each step of ``run``, the loop that ran last: the
+    rows its ``repro.train.step`` units left (the first step compiles)."""
+    rows = spans.rows("repro.train.step")[-len(run.losses):]
+    return rows["repro.train.step"].tolist()
+
+
+def steady_s(walls: list[float]) -> float:
+    return statistics.median(walls[1:])
 
 
 def train_phase(spec, args) -> None:
     say_cut(spec, args)
     run = train_loop(args, spec)
+    walls = step_walls(run)
     del run.state
     check(all(np.isfinite(run.losses)), f"non-finite loss: {run.losses}")
-    say("train", steps=len(run.losses), first_step_s=run.step_s[0],
-        compile_s=run.step_s[0] - steady_s(run), steady_step_s=steady_s(run),
-        tokens_per_s=args.batch * args.seq / steady_s(run),
+    say("train", steps=len(run.losses), first_step_s=walls[0],
+        compile_s=walls[0] - steady_s(walls), steady_step_s=steady_s(walls),
+        tokens_per_s=args.batch * args.seq / steady_s(walls),
         losses=",".join(repr(x) for x in run.losses))
 
     params = M.init_params(jax.random.PRNGKey(args.seed), spec)
@@ -307,8 +316,10 @@ def mesh_phase(spec, args_one, args_mesh) -> None:
     mesh run's state spread over all four devices."""
     say_cut(spec, args_mesh)
     one = train_loop(args_one, spec)
+    walls = {"one_device": step_walls(one)}
     del one.state
     run = train_loop(args_mesh, spec)
+    walls["mesh"] = step_walls(run)
     held = {d: 0 for d in jax.devices()[:4]}
     for leaf in jax.tree.leaves(run.state):
         for shard in leaf.addressable_shards:
@@ -326,8 +337,8 @@ def mesh_phase(spec, args_one, args_mesh) -> None:
           f"device memory in use is lopsided: {in_use}")
     del run.state
     for name, r in (("one_device", one), ("mesh", run)):
-        say("mesh", run=name, first_step_s=r.step_s[0],
-            steady_step_s=steady_s(r),
+        say("mesh", run=name, first_step_s=walls[name][0],
+            steady_step_s=steady_s(walls[name]),
             losses=",".join(repr(x) for x in r.losses))
     check(all(np.isfinite(run.losses)), f"non-finite loss: {run.losses}")
     d_abs, d_rel = deviation(run.losses, one.losses)

@@ -30,6 +30,7 @@ from typing import Any, Callable, Protocol, Sequence, runtime_checkable
 
 from repro.core.simulator import SimResult, SystemConfig
 from repro.core.workload import Parallelism, Trace
+from repro.runtime import spans
 
 
 @dataclass(frozen=True)
@@ -188,9 +189,10 @@ def run_sim_jobs(jobs: Sequence[Any],
         for (ji, ci), res in zip(members, results):
             slots[ji][ci] = res
     out = []
-    for ji, job in enumerate(jobs):
-        if not isinstance(job, SimJob):
-            out.append(job)
-            continue
-        out.append(job.finalize(list(slots[ji])))
+    with spans.span("repro.engine.finalize"):
+        for ji, job in enumerate(jobs):
+            if not isinstance(job, SimJob):
+                out.append(job)
+                continue
+            out.append(job.finalize(list(slots[ji])))
     return out

@@ -32,10 +32,17 @@ input, and they come in two flavours:
     compiled sweep — the pre-fusion behaviour, kept as the measurable
     baseline for the duration-pass-vs-sweep time split.
 
-``last_timings`` records the split after every ``simulate_batch``:
-``durations_s`` (host-side duration pass: the scalar loop when unfused, the
-memoized table packing when fused) and ``sweep_s`` (the compiled evaluation
-— pricing + sweep together when fused).
+Every ``simulate_batch`` runs under the spans of ``repro.runtime.spans``:
+``repro.engine.pack`` (the host-side duration pass: the scalar loop when
+unfused, the memoized table packing when fused), ``repro.engine.dispatch``
+(the compiled call returning), ``repro.engine.device_wait`` (both outputs
+ready), ``repro.engine.copy_back`` (to host numpy) and
+``repro.engine.assemble`` (busy accounting and the ``SimResult``s).
+``last_timings`` keeps the split of the most recent call, from the same
+span durations: ``durations_s`` is the pack, ``sweep_s`` the dispatch,
+wait and copy back (pricing + sweep together when fused).  On the device
+the fused call's operations sit under the named scopes
+``repro.engine.price`` and ``repro.engine.sweep``.
 
 Fidelity: each resource serializes its ops in issue order instead of the
 reference loop's arrival-order (FIFO) / freshest-first (LIFO) queue
@@ -47,7 +54,6 @@ populations over large traces.
 """
 from __future__ import annotations
 
-import time
 from collections.abc import Mapping
 from typing import Any, Sequence
 
@@ -61,6 +67,7 @@ from repro.core.simulator import (SimResult, SystemConfig, _SimPlan,
                                   batch_op_durations, build_sim_result,
                                   plan_duration_tables, plan_durations)
 from repro.core.workload import Parallelism, Trace
+from repro.runtime import spans
 
 
 @jax.jit
@@ -134,8 +141,10 @@ def _fused_eval(plan: _SimPlan):
             # op-major durations feed the sweep with contiguous per-op rows
             # (the loop body reads one row per step) and ship to host
             # without a transpose — busy accounting scatters op-major too
-            dur_t = batch_op_durations(plan, tables, xp=jnp, op_major=True)
-            return dur_t, _sweep_population(dur_t, parents)
+            with jax.named_scope("repro.engine.price"):
+                dur_t = batch_op_durations(plan, tables, xp=jnp, op_major=True)
+            with jax.named_scope("repro.engine.sweep"):
+                return dur_t, _sweep_population(dur_t, parents)
         fn = plan.pack_memo["_fused"] = jax.jit(fused)
     return fn
 
@@ -179,7 +188,7 @@ class JaxBackend:
         self.fused = fused
         self.name = "jax" if fused else "jax-unfused"
         # duration-pass vs compiled-evaluation wall-time split of the most
-        # recent simulate_batch (see module docstring)
+        # recent simulate_batch, from its spans (see module docstring)
         self.last_timings: dict[str, float] = {}
 
     def simulate(self, trace: Trace, cfg: SystemConfig, par: Parallelism, *,
@@ -197,59 +206,70 @@ class JaxBackend:
                        calls: Sequence[Any]) -> list[SimResult]:
         if not calls:
             return []
-        t0 = time.perf_counter()
         if self.fused:
-            plan, tables = plan_duration_tables(trace, calls)
-            parents = plan.pack_memo.get("_parents_dev")
-            t1 = time.perf_counter()
+            with spans.span("repro.engine.pack") as pack:
+                plan, tables = plan_duration_tables(trace, calls)
+                parents = plan.pack_memo.get("_parents_dev")
             # double precision scoped to the sweep (the global default stays
             # f32 for the model and kernel code paths)
             with jax.enable_x64(True):
-                if parents is None:
-                    # keep the static parent table resident on device — it
-                    # is the same every batch and re-uploading it costs
-                    # more than the entire class-table pack
-                    parents = jnp.asarray(_plan_parents(trace, plan))
-                    plan.pack_memo["_parents_dev"] = parents
-                dur_d, finish_d = _fused_eval(plan)(tables, parents)
-                dur = np.asarray(dur_d).T    # (P, n_ops) view, op-major data
-                finish = np.asarray(finish_d)[:plan.n_ops].T
+                with spans.span("repro.engine.dispatch") as dispatch:
+                    if parents is None:
+                        # keep the static parent table resident on device —
+                        # it is the same every batch and re-uploading it
+                        # costs more than the entire class-table pack
+                        parents = jnp.asarray(_plan_parents(trace, plan))
+                        plan.pack_memo["_parents_dev"] = parents
+                    dur_d, finish_d = _fused_eval(plan)(tables, parents)
+                with spans.span("repro.engine.device_wait") as wait:
+                    jax.block_until_ready((dur_d, finish_d))
+                with spans.span("repro.engine.copy_back") as copy:
+                    dur = np.asarray(dur_d).T    # (P, n_ops) view, op-major data
+                    finish = np.asarray(finish_d)[:plan.n_ops].T
         else:
-            plans_durs = [plan_durations(trace, c.cfg, c.par, c.pools)
-                          for c in calls]
-            plan = plans_durs[0][0]
-            parents = _plan_parents(trace, plan)
-            dur = np.asarray([d for _, d in plans_durs], dtype=np.float64)
-            t1 = time.perf_counter()
+            with spans.span("repro.engine.pack") as pack:
+                plans_durs = [plan_durations(trace, c.cfg, c.par, c.pools)
+                              for c in calls]
+                plan = plans_durs[0][0]
+                parents = _plan_parents(trace, plan)
+                dur = np.asarray([d for _, d in plans_durs], dtype=np.float64)
             with jax.enable_x64(True):
-                finish = np.asarray(_sweep_population(
-                    jnp.asarray(dur.T), jnp.asarray(parents)))[:plan.n_ops].T
-        t2 = time.perf_counter()
-        self.last_timings = {"durations_s": t1 - t0, "sweep_s": t2 - t1}
-        makespan = finish.max(axis=1) if plan.n_ops else np.zeros(len(calls))
-        res_of = np.asarray(plan.res_of, dtype=np.intp)
-        n_res = len(plan.res_names)
-        # whole-population busy accounting in one 2D scatter over
-        # (population, resource).  Either broadcast orientation accumulates
-        # each (member, resource) cell in increasing-uid order — the same
-        # order as the per-call np.bincount it replaces — so every row is
-        # bit-identical; iterate the orientation matching the duration
-        # matrix's memory layout (op-major from the fused kernel)
-        busy2d = np.zeros((len(calls), n_res), dtype=np.float64)
-        if self.fused:
-            np.add.at(busy2d.T,
-                      (res_of[:, None],
-                       np.arange(len(calls))[None, :]), dur.T)
-        else:
-            np.add.at(busy2d,
-                      (np.arange(len(calls))[:, None], res_of[None, :]), dur)
-        out: list[SimResult] = []
-        for k, call in enumerate(calls):
-            fin: Mapping = {}
-            if call.record_per_op or call.record_finish:
-                fin = FinishTimes(finish[k])
-            out.append(build_sim_result(
-                plan, makespan=float(makespan[k]), busy=busy2d[k].tolist(),
-                dur=dur[k], finish=fin,
-                record_per_op=call.record_per_op))
+                with spans.span("repro.engine.dispatch") as dispatch:
+                    finish_d = _sweep_population(jnp.asarray(dur.T),
+                                                 jnp.asarray(parents))
+                with spans.span("repro.engine.device_wait") as wait:
+                    finish_d.block_until_ready()
+                with spans.span("repro.engine.copy_back") as copy:
+                    finish = np.asarray(finish_d)[:plan.n_ops].T
+        self.last_timings = {
+            "durations_s": pack.seconds,
+            "sweep_s": dispatch.seconds + wait.seconds + copy.seconds}
+        with spans.span("repro.engine.assemble"):
+            makespan = finish.max(axis=1) if plan.n_ops else np.zeros(len(calls))
+            res_of = np.asarray(plan.res_of, dtype=np.intp)
+            n_res = len(plan.res_names)
+            # whole-population busy accounting in one 2D scatter over
+            # (population, resource).  Either broadcast orientation
+            # accumulates each (member, resource) cell in increasing-uid
+            # order — the same order as the per-call np.bincount it replaces
+            # — so every row is bit-identical; iterate the orientation
+            # matching the duration matrix's memory layout (op-major from
+            # the fused kernel)
+            busy2d = np.zeros((len(calls), n_res), dtype=np.float64)
+            if self.fused:
+                np.add.at(busy2d.T,
+                          (res_of[:, None],
+                           np.arange(len(calls))[None, :]), dur.T)
+            else:
+                np.add.at(busy2d,
+                          (np.arange(len(calls))[:, None], res_of[None, :]), dur)
+            out: list[SimResult] = []
+            for k, call in enumerate(calls):
+                fin: Mapping = {}
+                if call.record_per_op or call.record_finish:
+                    fin = FinishTimes(finish[k])
+                out.append(build_sim_result(
+                    plan, makespan=float(makespan[k]), busy=busy2d[k].tolist(),
+                    dur=dur[k], finish=fin,
+                    record_per_op=call.record_per_op))
         return out

@@ -40,6 +40,7 @@ from repro.core.rewards import Evaluation, Objective, get_objective
 from repro.core.scenario import EnvContext, Scenario, TrainScenario
 from repro.core.simulator import SystemConfig
 from repro.core.topology import Network, build_network
+from repro.runtime import spans
 
 
 @dataclass
@@ -270,42 +271,46 @@ class CosmicEnv:
         input order, so history and returned evaluations match what serial
         ``step`` calls would have produced.
         """
-        memo_on = caches_enabled()
-        if memo_on:
-            # evaluate each distinct uncached point once
-            memo = self._memo()
-            shared = self.eval_store is not None
-            keys = [self._point_key(c) for c in configs]
-            todo: dict[tuple, dict[str, Any]] = {}
-            for key, cfg in zip(keys, configs):
-                if key not in memo:
-                    todo.setdefault(key, cfg)
-            if shared:
-                # per-occurrence accounting matching serial step() calls:
-                # the first sighting of a new key is the miss, duplicates
-                # (within the batch or not) are hits
-                counted_new: set = set()
-                for key in keys:
-                    if key not in todo or key in counted_new:
-                        self.store_hits += 1
-                    else:
-                        self.store_misses += 1
-                        counted_new.add(key)
-            if todo:
-                evs = self._eval_many(list(todo.values()), workers)
-                memo.update(zip(todo.keys(), evs))
-                if self.eval_record is not None:
-                    for cfg, ev in zip(todo.values(), evs):
-                        self.eval_record(cfg, ev)
-            out = [memo[key] for key in keys]
-        else:
-            # caches off = the honest uncached baseline: every occurrence
-            # is evaluated, including within-batch duplicates
-            out = self._eval_many(list(configs), workers)
-        for cfg, ev in zip(configs, out):
-            self.history.append(StepRecord(len(self.history), cfg, ev.reward,
-                                           ev.latency_ms, ev.valid))
-        return out
+        with spans.unit("repro.engine.generation"):
+            spans.count("repro.engine.points", len(configs))
+            memo_on = caches_enabled()
+            if memo_on:
+                # evaluate each distinct uncached point once
+                memo = self._memo()
+                shared = self.eval_store is not None
+                keys = [self._point_key(c) for c in configs]
+                todo: dict[tuple, dict[str, Any]] = {}
+                for key, cfg in zip(keys, configs):
+                    if key not in memo:
+                        todo.setdefault(key, cfg)
+                if shared:
+                    # per-occurrence accounting matching serial step() calls:
+                    # the first sighting of a new key is the miss, duplicates
+                    # (within the batch or not) are hits
+                    counted_new: set = set()
+                    for key in keys:
+                        if key not in todo or key in counted_new:
+                            self.store_hits += 1
+                        else:
+                            self.store_misses += 1
+                            counted_new.add(key)
+                spans.count("repro.engine.evaluated", len(todo))
+                if todo:
+                    evs = self._eval_many(list(todo.values()), workers)
+                    memo.update(zip(todo.keys(), evs))
+                    if self.eval_record is not None:
+                        for cfg, ev in zip(todo.values(), evs):
+                            self.eval_record(cfg, ev)
+                out = [memo[key] for key in keys]
+            else:
+                # caches off = the honest uncached baseline: every occurrence
+                # is evaluated, including within-batch duplicates
+                spans.count("repro.engine.evaluated", len(configs))
+                out = self._eval_many(list(configs), workers)
+            for cfg, ev in zip(configs, out):
+                self.history.append(StepRecord(len(self.history), cfg, ev.reward,
+                                               ev.latency_ms, ev.valid))
+            return out
 
     def _eval_many(self, cfgs: list[dict[str, Any]],
                    workers: int) -> list[Evaluation]:
@@ -318,7 +323,8 @@ class CosmicEnv:
             # Takes precedence over the process pool: fanning single-point
             # evaluations out to workers would forfeit the shared-plan
             # sweep (and pay a per-worker jit compile).
-            jobs = [self.scenario.sim_job(self.context(c)) for c in cfgs]
+            with spans.span("repro.engine.jobs"):
+                jobs = [self.scenario.sim_job(self.context(c)) for c in cfgs]
             return run_sim_jobs(jobs, backend)
         if workers > 1 and len(cfgs) > 1 and self.backend == "reference":
             # only the pure-numpy backend fans out: every worker of a jax

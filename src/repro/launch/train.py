@@ -13,7 +13,6 @@ from __future__ import annotations
 import argparse
 import contextlib
 import functools
-import time
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Any
@@ -27,6 +26,7 @@ from repro.ckpt.checkpoint import AsyncCheckpointer, latest_step, restore
 from repro.data.pipeline import DataConfig, Prefetcher, SyntheticLM
 from repro.launch.mesh import make_mesh
 from repro.parallel.sharding import NULL_PLAN, plan_for_mesh, tree_shardings
+from repro.runtime import spans
 from repro.runtime.compile_cache import use_compile_cache
 from repro.runtime.fault import Heartbeat, StragglerMonitor
 from repro.train import optimizer as opt
@@ -60,11 +60,11 @@ def build(spec, mesh, cfg: RunConfig, seed: int = 0):
 @dataclass
 class TrainRun:
     """What ``train_loop`` leaves behind: the step it stopped at, the loss
-    and the wall seconds (the first includes compilation) of every step it
-    ran, and the final state."""
+    of every step it ran, and the final state.  Each step is a
+    ``repro.train.step`` unit of ``repro.runtime.spans``: its row holds the
+    step's wall seconds (the first includes compilation) and the split."""
     final: int
     losses: list[float]
-    step_s: list[float]
     state: Any
 
 
@@ -98,37 +98,46 @@ def train_loop(args, spec, fail_at: int | None = None) -> TrainRun:
         straggler = StragglerMonitor(k_sigma=args.straggler_sigma)
 
         losses: list[float] = []
-        step_s: list[float] = []
         it = iter(prefetch)
+        step = start - 1
         try:
-            for step, batch in it:
-                if step >= args.steps:
-                    break
-                if fail_at is not None and step == fail_at:
-                    raise RuntimeError(f"injected failure at step {step}")
-                t0 = time.time()
-                state, metrics = jit_step(state, jax.device_put(batch, batch_sh))
-                loss = float(metrics["loss"])
-                losses.append(loss)
-                dt = time.time() - t0
-                step_s.append(dt)
-                if straggler.observe(step, dt):
-                    print(f"[straggler] step {step} took {dt:.3f}s "
-                          f"(mean {straggler.mean:.3f}s) — mitigation hook fired", flush=True)
-                if hb:
-                    hb.beat(step)
-                if ckpt and (step + 1) % args.ckpt_every == 0:
-                    ckpt.save(state, step + 1)
-                if step % args.log_every == 0:
-                    print(f"[train] step {step} loss {loss:.4f} "
-                          f"({dt*1e3:.0f} ms)", flush=True)
+            while True:
+                with spans.unit("repro.train.step") as unit:
+                    with spans.span("repro.train.input"):
+                        item = next(it, None)
+                    if item is None or item[0] >= args.steps:
+                        unit.drop()
+                        break
+                    step, batch = item
+                    if fail_at is not None and step == fail_at:
+                        raise RuntimeError(f"injected failure at step {step}")
+                    with spans.span("repro.train.put"):
+                        batch = jax.device_put(batch, batch_sh)
+                    with spans.span("repro.train.dispatch"):
+                        state, metrics = jit_step(state, batch)
+                    with spans.span("repro.train.loss_sync"):
+                        loss = float(metrics["loss"])
+                    losses.append(loss)
+                    with spans.span("repro.train.bookkeeping"):
+                        dt = unit.elapsed()
+                        if straggler.observe(step, dt):
+                            print(f"[straggler] step {step} took {dt:.3f}s "
+                                  f"(mean {straggler.mean:.3f}s) — mitigation "
+                                  f"hook fired", flush=True)
+                        if hb:
+                            hb.beat(step)
+                        if ckpt and (step + 1) % args.ckpt_every == 0:
+                            ckpt.save(state, step + 1)
+                        if step % args.log_every == 0:
+                            print(f"[train] step {step} loss {loss:.4f} "
+                                  f"({dt*1e3:.0f} ms)", flush=True)
             final = min(args.steps, step + 1)
         finally:
             prefetch.close()
         if ckpt:
             ckpt.save(state, final, block=True)
         print(f"[train] done at step {final}; loss {losses[0]:.4f} -> {losses[-1]:.4f}", flush=True)
-        return TrainRun(final, losses, step_s, state)
+        return TrainRun(final, losses, state)
 
 
 def parse_args(argv: list[str] | None = None) -> argparse.Namespace:

@@ -56,21 +56,26 @@ def layer_cache_defs(spec: ArchSpec, ld: LayerDef, batch: int, seq: int,
 
 
 def _apply_train(p, x, positions, ld: LayerDef, spec: ArchSpec, plan: ShardingPlan):
+    # the named scopes label the training step's device operations (and
+    # their gradients) in a profiler trace
     h = rmsnorm(x, p["norm1"], spec.norm_eps)
     if ld.mixer == "mamba":
-        y = mb.mamba_fwd(p["mixer"], h, spec, plan)
+        with jax.named_scope("repro.train.mamba"):
+            y = mb.mamba_fwd(p["mixer"], h, spec, plan)
     else:
         window = spec.sliding_window if ld.mixer == "attn_local" else 0
-        y = attn.attention_fwd(p["mixer"], h, positions, spec, plan, window=window)
+        with jax.named_scope("repro.train.attention"):
+            y = attn.attention_fwd(p["mixer"], h, positions, spec, plan, window=window)
     x = x + y
     aux = jnp.zeros((), jnp.float32)
     if ld.ffn != "none":
         h = rmsnorm(x, p["norm2"], spec.norm_eps)
-        if ld.ffn == "moe":
-            y, a = moem.moe_apply(p["ffn"], h, spec, plan)
-            aux = aux + a["lb_loss"]
-        else:
-            y = mlpm.mlp_apply(p["ffn"], h, spec, plan)
+        with jax.named_scope("repro.train.ffn"):
+            if ld.ffn == "moe":
+                y, a = moem.moe_apply(p["ffn"], h, spec, plan)
+                aux = aux + a["lb_loss"]
+            else:
+                y = mlpm.mlp_apply(p["ffn"], h, spec, plan)
         x = x + y
     return x, aux
 

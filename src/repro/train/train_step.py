@@ -55,16 +55,16 @@ def make_loss_fn(spec: ArchSpec, plan: ShardingPlan, cfg: RunConfig):
     from repro.train.loss import chunked_cross_entropy
 
     def loss_fn(params, batch):
-        if cfg.loss_chunk > 0:
-            hidden, aux = M.forward_hidden(params, batch["inputs"], spec, plan,
-                                           compute_dtype=cfg.compute_dtype,
-                                           remat=cfg.remat)
-            ce = chunked_cross_entropy(hidden, M.head_fn(params, spec, plan),
-                                       batch["labels"], chunk=cfg.loss_chunk)
-        else:
-            logits, aux = M.forward(params, batch["inputs"], spec, plan,
-                                    compute_dtype=cfg.compute_dtype, remat=cfg.remat)
-            ce = cross_entropy(logits, batch["labels"])
+        hidden, aux = M.forward_hidden(params, batch["inputs"], spec, plan,
+                                       compute_dtype=cfg.compute_dtype,
+                                       remat=cfg.remat)
+        with jax.named_scope("repro.train.lm_head_loss"):
+            head = M.head_fn(params, spec, plan)
+            if cfg.loss_chunk > 0:
+                ce = chunked_cross_entropy(hidden, head, batch["labels"],
+                                           chunk=cfg.loss_chunk)
+            else:
+                ce = cross_entropy(head(hidden), batch["labels"])
         loss = ce + cfg.lb_weight * aux
         return loss, {"ce": ce, "lb": aux}
 
@@ -114,7 +114,8 @@ def make_train_step(spec: ArchSpec, plan: ShardingPlan = NULL_PLAN,
             grads = jax.tree.map(lambda g: g / k, gsum)
             loss = lsum / k
             metrics = {}
-        new_state, om = opt.apply_updates(state, grads, cfg.opt)
+        with jax.named_scope("repro.train.optimizer"):
+            new_state, om = opt.apply_updates(state, grads, cfg.opt)
         out = {"loss": loss, **{k: v for k, v in metrics.items()}, **om}
         return new_state, out
 
