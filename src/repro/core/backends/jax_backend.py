@@ -25,7 +25,14 @@ input, and they come in two flavours:
     prices every duration class x population member with the vectorized
     collective evaluator (``collectives.multidim_collective_time_vec``) and
     feeds the durations straight into the scheduling sweep — no host
-    round-trip between pricing and scheduling.
+    round-trip between pricing and scheduling.  The same call reduces on
+    the device what the host reads: each member's makespan, its busy time
+    per resource, and the finish times of the trace's marked uids
+    (``workload.wave_mark_uids``) where a call records finish times — a
+    (1 + n_res + k, P) array instead of two (n_ops, P) matrices.  The full
+    duration and finish matrices come back only for a batch with a
+    ``record_per_op`` call, or with a ``record_finish`` call on a trace
+    that marks no waves.
   * UNFUSED (``JaxBackend(fused=False)``, registered as ``jax-unfused``):
     the scalar per-call duration pass (vectorized roofline + memoized
     scalar collective model via ``simulator.plan_durations``) feeding the
@@ -35,14 +42,16 @@ input, and they come in two flavours:
 Every ``simulate_batch`` runs under the spans of ``repro.runtime.spans``:
 ``repro.engine.pack`` (the host-side duration pass: the scalar loop when
 unfused, the memoized table packing when fused), ``repro.engine.dispatch``
-(the compiled call returning), ``repro.engine.device_wait`` (both outputs
-ready), ``repro.engine.copy_back`` (to host numpy) and
-``repro.engine.assemble`` (busy accounting and the ``SimResult``s).
+(the compiled call returning), ``repro.engine.device_wait`` (the outputs
+ready), ``repro.engine.copy_back`` (to host numpy; its bytes are the
+counter ``repro.engine.copy_back_bytes``) and ``repro.engine.assemble``
+(the ``SimResult``s, and the busy accounting when unfused).
 ``last_timings`` keeps the split of the most recent call, from the same
 span durations: ``durations_s`` is the pack, ``sweep_s`` the dispatch,
 wait and copy back (pricing + sweep together when fused).  On the device
 the fused call's operations sit under the named scopes
-``repro.engine.price`` and ``repro.engine.sweep``.
+``repro.engine.price``, ``repro.engine.sweep`` and
+``repro.engine.reduce``.
 
 Fidelity: each resource serializes its ops in issue order instead of the
 reference loop's arrival-order (FIFO) / freshest-first (LIFO) queue
@@ -66,7 +75,7 @@ from jax import lax
 from repro.core.simulator import (SimResult, SystemConfig, _SimPlan,
                                   batch_op_durations, build_sim_result,
                                   plan_duration_tables, plan_durations)
-from repro.core.workload import Parallelism, Trace
+from repro.core.workload import Parallelism, Trace, wave_mark_uids
 from repro.runtime import spans
 
 
@@ -127,51 +136,130 @@ def _plan_parents(trace: Trace, plan: _SimPlan) -> np.ndarray:
     return parents
 
 
-def _fused_eval(plan: _SimPlan):
-    """The per-plan fused kernel: population duration tables in, per-op
-    durations AND finish times out, one jit-compiled call.
+# ops per partial sum of the busy accounting (see ``_busy_chunks``)
+BUSY_CHUNK = 32
+
+
+def _busy_chunks(plan: _SimPlan) -> tuple[np.ndarray, np.ndarray]:
+    """Static gather indices for the busy sums: each resource's ops in uid
+    order, cut into rows of ``BUSY_CHUNK`` padded with ``n_ops`` (a zero
+    row), and each row's resource (sorted).  Summing each row on the device
+    and then scattering only the rows per resource is far cheaper on the
+    TPU than scattering every op: on one TPU v5e, over a 25,872-op
+    request-stream trace and 32 members, a sorted ``segment_sum`` of every
+    op added 2.2 ms to a 41.9 ms call, the row sums 0.2 ms (rows of 16 to
+    128 ops alike)."""
+    n, c = plan.n_ops, BUSY_CHUNK
+    res_of = np.asarray(plan.res_of, dtype=np.intp)
+    counts = np.bincount(res_of, minlength=len(plan.res_names))
+    rows = -(-counts // c)
+    # each op's rank within its resource, in uid order
+    order = np.argsort(res_of, kind="stable")
+    rank = np.arange(n) - np.repeat(np.cumsum(counts) - counts, counts)
+    row = np.repeat(np.cumsum(rows) - rows, counts) + rank // c
+    chunks = np.full((int(rows.sum()), c), n, dtype=np.int32)
+    chunks[row, rank % c] = order
+    return chunks, np.repeat(np.arange(len(counts), dtype=np.int32), rows)
+
+
+def _plan_statics(trace: Trace, plan: _SimPlan) -> tuple[jnp.ndarray, ...]:
+    """The fused call's static per-plan inputs, on the device: the
+    augmented-parent table and the busy sums' row indices
+    (``_busy_chunks``).  Uploaded once and kept on the plan — re-uploading
+    them every batch costs more than the entire class-table pack."""
+    statics = plan.pack_memo.get("_statics_dev")
+    if statics is None:
+        statics = plan.pack_memo["_statics_dev"] = tuple(
+            jnp.asarray(a) for a in (_plan_parents(trace, plan),
+                                     *_busy_chunks(plan)))
+    return statics
+
+
+def _plan_marks(trace: Trace, plan: _SimPlan) -> tuple[np.ndarray, jnp.ndarray]:
+    """The trace's marked uids (``workload.wave_mark_uids``), on the host
+    and on the device, kept on the plan."""
+    marks = plan.pack_memo.get("_marks")
+    if marks is None:
+        uids = wave_mark_uids(trace)
+        marks = plan.pack_memo["_marks"] = (uids, jnp.asarray(uids.astype(np.int32)))
+    return marks
+
+
+def _fused_eval(plan: _SimPlan, full: bool):
+    """The per-plan fused kernel: population duration tables in, one
+    (1 + n_res + k, P) array out — each member's makespan, its busy time
+    per resource, and the finish times of the ``k`` uids asked for (none
+    where ``uids`` is None) — all from one jit-compiled call.  ``full``
+    also returns every op's duration and finish time (op-major, the finish
+    matrix with its dummy row last).
 
     Compiled per plan (the plan's scatter index arrays are closure
     constants, so the function identity must be plan-specific) and cached
-    on it; XLA re-specializes per (population size, padded dim count) —
-    both stable across the generations of a search."""
-    fn = plan.pack_memo.get("_fused")
+    on it; XLA re-specializes per (population size, padded dim count, k) —
+    all stable across the generations of a search."""
+    key = "_fused_full" if full else "_fused"
+    fn = plan.pack_memo.get(key)
     if fn is None:
-        def fused(tables, parents):
+        n_ops, n_res = plan.n_ops, len(plan.res_names)
+
+        def fused(tables, parents, chunks, chunk_res, uids):
             # op-major durations feed the sweep with contiguous per-op rows
-            # (the loop body reads one row per step) and ship to host
-            # without a transpose — busy accounting scatters op-major too
+            # (the loop body reads one row per step)
             with jax.named_scope("repro.engine.price"):
                 dur_t = batch_op_durations(plan, tables, xp=jnp, op_major=True)
             with jax.named_scope("repro.engine.sweep"):
-                return dur_t, _sweep_population(dur_t, parents)
-        fn = plan.pack_memo["_fused"] = jax.jit(fused)
+                finish = _sweep_population(dur_t, parents)
+            with jax.named_scope("repro.engine.reduce"):
+                p = dur_t.shape[1]
+                makespan = (finish[:n_ops].max(axis=0, keepdims=True) if n_ops
+                            else jnp.zeros((1, p), finish.dtype))
+                zero = jnp.zeros((1, p), dur_t.dtype)
+                part = jnp.concatenate([dur_t, zero])[chunks].sum(axis=1)
+                busy = jax.ops.segment_sum(part, chunk_res, num_segments=n_res,
+                                           indices_are_sorted=True)
+                rows = [makespan, busy] + ([] if uids is None else [finish[uids]])
+                out = jnp.concatenate(rows)
+            return (out, dur_t, finish) if full else out
+        fn = plan.pack_memo[key] = jax.jit(fused)
     return fn
 
 
 class FinishTimes(Mapping):
-    """``SimResult.op_finish_us`` backed by the sweep's finish row — dict
-    semantics (uid -> finish time) without materializing tens of thousands
-    of boxed floats per design point; scenarios only read the wave-mark
-    uids off it."""
+    """``SimResult.op_finish_us`` backed by one member's finish times from
+    the sweep — dict semantics (uid -> finish time) without materializing
+    tens of thousands of boxed floats per design point.  ``uids`` (sorted)
+    names the ops ``row`` holds; None means every op, in uid order.
+    Scenarios read the wave times through ``take``."""
 
-    __slots__ = ("_row",)
+    __slots__ = ("_row", "_uids")
 
-    def __init__(self, row: np.ndarray) -> None:
-        self._row = row
+    def __init__(self, row: np.ndarray, uids: np.ndarray | None = None) -> None:
+        self._row, self._uids = row, uids
+
+    def take(self, uids: np.ndarray) -> np.ndarray:
+        """The finish times of ``uids``, as one array (a positional
+        gather); KeyError for a uid this mapping does not hold."""
+        uids = np.asarray(uids, dtype=np.intp)
+        if self._uids is None:
+            pos, held = uids, (uids >= 0) & (uids < len(self._row))
+        else:
+            pos = np.searchsorted(self._uids, uids)
+            held = pos < len(self._uids)
+            held[held] = self._uids[pos[held]] == uids[held]
+        if not held.all():
+            # dict semantics, not array semantics: never wrap negatively
+            raise KeyError(int(uids[~held][0]))
+        return self._row[pos]
 
     def __getitem__(self, uid: int) -> float:
-        # dict semantics, not array semantics: unknown uids must raise
-        # KeyError (so `in`/`.get()` work) and never wrap negatively
-        if not 0 <= uid < len(self._row):
-            raise KeyError(uid)
-        return float(self._row[uid])
+        return float(self.take([uid])[0])
 
     def __len__(self) -> int:
         return len(self._row)
 
     def __iter__(self):
-        return iter(range(len(self._row)))
+        return iter(range(len(self._row)) if self._uids is None
+                    else self._uids.tolist())
 
 
 class JaxBackend:
@@ -207,26 +295,29 @@ class JaxBackend:
         if not calls:
             return []
         if self.fused:
+            # the full matrices only where a call asks for every op, or for
+            # finish times on a trace that marks no waves; otherwise the
+            # makespan, the busy time and the marked uids' finish times
+            wants = any(c.record_finish for c in calls)
+            marked = wants and bool(trace.meta.get("wave_marks"))
+            full = any(c.record_per_op for c in calls) or (wants and not marked)
             with spans.span("repro.engine.pack") as pack:
                 plan, tables = plan_duration_tables(trace, calls)
-                parents = plan.pack_memo.get("_parents_dev")
             # double precision scoped to the sweep (the global default stays
             # f32 for the model and kernel code paths)
             with jax.enable_x64(True):
                 with spans.span("repro.engine.dispatch") as dispatch:
-                    if parents is None:
-                        # keep the static parent table resident on device —
-                        # it is the same every batch and re-uploading it
-                        # costs more than the entire class-table pack
-                        parents = jnp.asarray(_plan_parents(trace, plan))
-                        plan.pack_memo["_parents_dev"] = parents
-                    dur_d, finish_d = _fused_eval(plan)(tables, parents)
+                    uids, uids_d = (_plan_marks(trace, plan)
+                                    if marked and not full else (None, None))
+                    got = _fused_eval(plan, full)(
+                        tables, *_plan_statics(trace, plan), uids_d)
                 with spans.span("repro.engine.device_wait") as wait:
-                    jax.block_until_ready((dur_d, finish_d))
+                    jax.block_until_ready(got)
                 with spans.span("repro.engine.copy_back") as copy:
-                    dur = np.asarray(dur_d).T    # (P, n_ops) view, op-major data
-                    finish = np.asarray(finish_d)[:plan.n_ops].T
+                    host = [np.asarray(a) for a in (got if full else (got,))]
+            copied = sum(a.nbytes for a in host)
         else:
+            full = True        # the unfused sweep returns every finish time
             with spans.span("repro.engine.pack") as pack:
                 plans_durs = [plan_durations(trace, c.cfg, c.par, c.pools)
                               for c in calls]
@@ -240,36 +331,41 @@ class JaxBackend:
                 with spans.span("repro.engine.device_wait") as wait:
                     finish_d.block_until_ready()
                 with spans.span("repro.engine.copy_back") as copy:
-                    finish = np.asarray(finish_d)[:plan.n_ops].T
+                    finish_all = np.asarray(finish_d)
+                    finish = finish_all[:plan.n_ops].T
+            copied = finish_all.nbytes
+        spans.count("repro.engine.copy_back_bytes", copied)
         self.last_timings = {
             "durations_s": pack.seconds,
             "sweep_s": dispatch.seconds + wait.seconds + copy.seconds}
         with spans.span("repro.engine.assemble"):
-            makespan = finish.max(axis=1) if plan.n_ops else np.zeros(len(calls))
-            res_of = np.asarray(plan.res_of, dtype=np.intp)
             n_res = len(plan.res_names)
-            # whole-population busy accounting in one 2D scatter over
-            # (population, resource).  Either broadcast orientation
-            # accumulates each (member, resource) cell in increasing-uid
-            # order — the same order as the per-call np.bincount it replaces
-            # — so every row is bit-identical; iterate the orientation
-            # matching the duration matrix's memory layout (op-major from
-            # the fused kernel)
-            busy2d = np.zeros((len(calls), n_res), dtype=np.float64)
             if self.fused:
-                np.add.at(busy2d.T,
-                          (res_of[:, None],
-                           np.arange(len(calls))[None, :]), dur.T)
+                # (P, 1 + n_res + k) views: makespan, busy, marked finish
+                cols = host[0].T
+                makespan, busy2d = cols[:, 0], cols[:, 1:1 + n_res]
+                if full:
+                    dur = host[1].T                      # (P, n_ops) views
+                    finish = host[2][:plan.n_ops].T
             else:
+                makespan = (finish.max(axis=1) if plan.n_ops
+                            else np.zeros(len(calls)))
+                # whole-population busy accounting in one 2D scatter over
+                # (population, resource): each (member, resource) cell
+                # accumulates in increasing-uid order, as the per-call
+                # np.bincount it replaces
+                res_of = np.asarray(plan.res_of, dtype=np.intp)
+                busy2d = np.zeros((len(calls), n_res), dtype=np.float64)
                 np.add.at(busy2d,
                           (np.arange(len(calls))[:, None], res_of[None, :]), dur)
             out: list[SimResult] = []
             for k, call in enumerate(calls):
                 fin: Mapping = {}
                 if call.record_per_op or call.record_finish:
-                    fin = FinishTimes(finish[k])
+                    fin = (FinishTimes(finish[k]) if full
+                           else FinishTimes(cols[k, 1 + n_res:], uids))
                 out.append(build_sim_result(
                     plan, makespan=float(makespan[k]), busy=busy2d[k].tolist(),
-                    dur=dur[k], finish=fin,
+                    dur=dur[k] if full else (), finish=fin,
                     record_per_op=call.record_per_op))
         return out

@@ -57,7 +57,7 @@ from repro.core.topology import (Cluster, Network, partition_cluster,
                                  sub_network, sub_network_indexed)
 from repro.core.workload import (Parallelism, Trace, Wave, WaveSegment,
                                  compose_phases, compose_request_waves,
-                                 generate_trace)
+                                 generate_trace, wave_time_index)
 
 
 @dataclass(frozen=True)
@@ -281,46 +281,23 @@ _serving_wave_trace_cached = \
     switchable_lru_cache(maxsize=512)(_serving_wave_trace_impl)
 
 
-def _wave_mark_index(trace: Trace):
-    """Flattened wave-mark tail uids + segment offsets, built once and
-    piggybacked on the (cached, immutable) trace so the per-evaluation read
-    is two fancy gathers instead of thousands of dict lookups."""
-    idx = getattr(trace, "_wave_mark_idx", None)
-    if idx is None:
-        first: list[int] = []
-        done: list[int] = []
-        off_f = [0]
-        off_d = [0]
-        for mk in trace.meta["wave_marks"]:
-            first.extend(mk["seg_tails"][1])
-            done.extend(mk["seg_tails"][-1])
-            off_f.append(len(first))
-            off_d.append(len(done))
-        idx = (np.asarray(first, dtype=np.intp), np.asarray(off_f[:-1]),
-               np.asarray(done, dtype=np.intp), np.asarray(off_d[:-1]))
-        trace._wave_mark_idx = idx
-    return idx
-
-
 def _wave_times_ms(trace: Trace, res: SimResult) -> list[tuple[float, float]]:
     """Per wave ``(first_token_ms, last_token_ms)`` completion times, read
     off the recorded op finish times through ``meta["wave_marks"]``."""
+    waves = len(trace.meta["wave_marks"])
+    if not waves:
+        return []
+    uids, starts = wave_time_index(trace)
     fin = res.op_finish_us
-    row = getattr(fin, "_row", None)
-    if row is not None and trace.meta["wave_marks"]:
-        # vectorized backends expose the finish times as one array row:
-        # segment-max the tail uids instead of looping dict reads (reduceat
-        # takes the max over the same floats, so values are bit-identical)
-        uids_f, off_f, uids_d, off_d = _wave_mark_index(trace)
-        t_first = np.maximum.reduceat(row[uids_f], off_f) / 1e3
-        t_done = np.maximum.reduceat(row[uids_d], off_d) / 1e3
-        return list(zip(t_first.tolist(), t_done.tolist()))
-    out = []
-    for mk in trace.meta["wave_marks"]:
-        t_first = max(fin[u] for u in mk["seg_tails"][1]) / 1e3
-        t_done = max(fin[u] for u in mk["seg_tails"][-1]) / 1e3
-        out.append((t_first, t_done))
-    return out
+    # vectorized backends expose the finish times as one array: gather every
+    # wave's tail uids at once instead of looping dict reads, then
+    # segment-max them (the gather copies and reduceat takes the max over
+    # the same floats, so values are bit-identical either way)
+    take = getattr(fin, "take", None)
+    got = (take(uids) if take is not None
+           else np.array([fin[u] for u in uids.tolist()], dtype=np.float64))
+    t = np.maximum.reduceat(got, starts) / 1e3
+    return list(zip(t[:waves].tolist(), t[waves:].tolist()))
 
 
 def _compose_memo(pre: Trace, dec: Trace, xfer_bytes: float,

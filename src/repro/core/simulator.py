@@ -642,7 +642,11 @@ def simulate(trace: Trace, cfg: SystemConfig, par: Parallelism, *,
     ``op_finish_us``); ``record_finish`` materializes only
     ``SimResult.op_finish_us`` — the cheaper flag streaming scenarios use
     per design point to read wave TTFT/TPOT without allocating the per-op
-    duration dict.  Both are off on the batched DSE hot path.
+    duration dict.  On a trace that marks its waves (``meta["wave_marks"]``)
+    a backend may then hold only the marked uids' finish times
+    (``workload.wave_mark_uids``; the fused ``jax`` backend does), and any
+    other uid raises ``KeyError``.  Both are off on the batched DSE hot
+    path.
 
     ``verify=True`` statically checks the trace's scheduling plan first
     (dependency-DAG acyclicity, dangling dep/resource references, pool

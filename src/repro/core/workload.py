@@ -16,6 +16,8 @@ import math
 from dataclasses import dataclass, field
 from typing import Any, Literal
 
+import numpy as np
+
 from repro.configs.base import ArchSpec, LayerDef
 from repro.core.cache import switchable_lru_cache
 
@@ -443,6 +445,29 @@ def compose_request_waves(waves: list[Wave],
                       "xfer_uids": xfer_uids})
     pools = sorted({seg.pool for w in waves for seg in w.segments})
     return Trace(ops, meta=dict(meta or {}, pools=pools, wave_marks=marks))
+
+
+def wave_time_index(trace: Trace) -> tuple[np.ndarray, np.ndarray]:
+    """The op uids whose finish times give each wave's first-token and
+    completion times, grouped: ``meta["wave_marks"]``' ``seg_tails[1]`` of
+    waves 0..W-1, then ``seg_tails[-1]`` of waves 0..W-1, with each group's
+    start offset (a wave's time is its group's latest finish).  Built once
+    and piggybacked on the (cached, immutable) trace."""
+    idx = getattr(trace, "_wave_time_idx", None)
+    if idx is None:
+        marks = trace.meta["wave_marks"]
+        groups = ([mk["seg_tails"][1] for mk in marks]
+                  + [mk["seg_tails"][-1] for mk in marks])
+        idx = (np.asarray([u for g in groups for u in g], dtype=np.intp),
+               np.cumsum([0] + [len(g) for g in groups])[:-1])
+        trace._wave_time_idx = idx
+    return idx
+
+
+def wave_mark_uids(trace: Trace) -> np.ndarray:
+    """The marked uids: every uid ``wave_time_index`` names, sorted and
+    unique — the only ops whose finish times a scenario reads."""
+    return np.unique(wave_time_index(trace)[0])
 
 
 def compose_phases(segments: list[tuple[Trace, int]],

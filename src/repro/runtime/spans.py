@@ -54,6 +54,7 @@ SPANS: dict[str, str | None] = {
 COUNTERS: dict[str, str] = {
     "repro.engine.points": "repro.engine.generation",   # points handed in
     "repro.engine.evaluated": "repro.engine.generation",  # evaluation memo misses
+    "repro.engine.copy_back_bytes": "repro.engine.generation",  # device to host
 }
 CAPACITY = 65_536
 

@@ -296,3 +296,119 @@ def test_backends_do_not_cross_hit_a_shared_eval_store():
     assert env_ref.store_misses == 1 and env_ref.store_hits == 0
     assert env_jax.store_misses == 1 and env_jax.store_hits == 0
     assert len(store) == 2
+
+
+# ---------------------------------------------------------------------------
+# what the fused call copies back: the makespan, the busy time and the
+# finish times of the wave-marked uids, unless every op is asked for
+# ---------------------------------------------------------------------------
+
+WAVE_SCENARIOS = {
+    "stream": (RequestStreamScenario(n_requests=24, seq=1024,
+                                     decode_tokens=16, rate_rps=16.0, seed=3),
+               "goodput", dict(prefill_frac=0.5, decode_batch=4,
+                               batch_window_ms=50.0, max_inflight=2)),
+    "disagg": (DisaggServeScenario(64, 2048, 16), "latency",
+               dict(prefill_frac=0.5, decode_batch=4)),
+}
+
+
+def _wave_calls(name: str) -> list[SimCall]:
+    """One population's calls over a scenario's shared wave trace."""
+    sc, obj, extra = WAVE_SCENARIOS[name]
+    env = system_env("qwen2-1.5b", "system2", scenario=sc, objective=obj,
+                     backend="jax")
+    cfgs = [dict(BASE_CFG, chunks=c, sched_policy=p, **extra)
+            for c in (2, 8) for p in ("fifo", "lifo")]
+    calls = [c for cfg in cfgs
+             for c in env.scenario.sim_job(env.context(cfg)).calls]
+    assert all(c.trace is calls[0].trace and c.record_finish for c in calls)
+    return calls
+
+
+@pytest.mark.parametrize("name", sorted(WAVE_SCENARIOS))
+def test_jax_fused_matches_unfused_on_wave_traces(name):
+    """Makespan and wave times bit for bit, busy to f64 rounding: the
+    reductions moved to the device and nothing else."""
+    from repro.core.scenario import _wave_times_ms
+
+    calls = _wave_calls(name)
+    tr = calls[0].trace
+    fused = get_backend("jax").simulate_batch(tr, calls)
+    unfused = get_backend("jax-unfused").simulate_batch(tr, calls)
+    for got, want in zip(fused, unfused):
+        assert got.makespan_us == want.makespan_us
+        assert _wave_times_ms(tr, got) == _wave_times_ms(tr, want)
+        assert _rel(got.compute_busy_us, want.compute_busy_us) < RTOL
+        assert got.comm_busy_us.keys() == want.comm_busy_us.keys()
+        for k, v in want.comm_busy_us.items():
+            assert _rel(got.comm_busy_us[k], v) < RTOL
+
+
+def test_jax_record_finish_holds_only_the_marked_uids():
+    from repro.core.workload import wave_mark_uids
+
+    calls = _wave_calls("stream")
+    tr = calls[0].trace
+    got = get_backend("jax").simulate_batch(tr, calls)[0].op_finish_us
+    want = get_backend("jax-unfused").simulate_batch(tr, calls)[0].op_finish_us
+    marked = wave_mark_uids(tr).tolist()
+    assert 0 < len(marked) < len(tr.ops) // 4
+    assert list(got) == marked and len(got) == len(marked)
+    assert all(got[u] == want[u] for u in marked)
+    assert got.take(marked[::-1]).tolist() == [want[u] for u in marked[::-1]]
+    unmarked = next(u for u in range(len(tr.ops)) if u not in set(marked))
+    for uid in (unmarked, -1, len(tr.ops)):
+        assert uid not in got
+        with pytest.raises(KeyError):
+            got[uid]
+    with pytest.raises(KeyError):
+        got.take([marked[0], unmarked])
+
+
+@pytest.mark.parametrize("ask", ["record_per_op", "finish_without_wave_marks"])
+def test_jax_full_matrices_where_every_op_is_asked_for(ask):
+    """A call that asks for every op, or finish times on a trace that marks
+    no waves, still gets each op's duration and finish time."""
+    from dataclasses import replace
+
+    from repro.core.workload import Trace
+
+    calls = _wave_calls("stream")
+    tr = calls[0].trace
+    if ask == "record_per_op":
+        calls = [replace(calls[0], record_per_op=True)] + calls[1:]
+    else:
+        tr = Trace(tr.ops, meta={k: v for k, v in tr.meta.items()
+                                 if k != "wave_marks"})
+        calls = [replace(c, trace=tr) for c in calls]
+    got = get_backend("jax").simulate_batch(tr, calls)
+    want = get_backend("jax-unfused").simulate_batch(tr, calls)
+    n = len(tr.ops)
+    assert len(got[0].op_finish_us) == n == len(want[0].op_finish_us)
+    assert all(got[0].op_finish_us[u] == want[0].op_finish_us[u]
+               for u in range(0, n, 97))
+    if ask == "record_per_op":
+        assert len(got[0].per_op_us) == n
+        assert all(_rel(got[0].per_op_us[u], want[0].per_op_us[u]) < RTOL
+                   for u in range(n))
+    assert [g.makespan_us for g in got] == [w.makespan_us for w in want]
+
+
+def test_jax_copy_back_bytes_of_a_stream_batch():
+    from repro.core.simulator import plan_duration_tables
+    from repro.core.workload import wave_mark_uids
+    from repro.runtime import spans
+
+    calls = _wave_calls("stream")
+    tr = calls[0].trace
+    n_res = len(plan_duration_tables(tr, calls[:1])[0].res_names)
+    gen = "repro.engine.generation"
+    with spans.unit(gen):
+        get_backend("jax").simulate_batch(tr, calls)
+    copied = spans.rows(gen)[-1]["repro.engine.copy_back_bytes"]
+    assert 0 < copied <= (len(wave_mark_uids(tr)) + 1 + n_res) * len(calls) * 8
+    with spans.unit(gen):
+        get_backend("jax-unfused").simulate_batch(tr, calls)
+    assert spans.rows(gen)[-1]["repro.engine.copy_back_bytes"] \
+        >= len(tr.ops) * len(calls) * 8

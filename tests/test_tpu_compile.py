@@ -93,9 +93,12 @@ def test_ssd_scan_mamba2_130m_width(one_chip):
 
 def test_fused_eval_request_stream_x64(one_chip):
     """The fused jax backend's whole compiled call (f64 collective and
-    roofline pricing plus the scheduling sweep) on a 48-request stream
-    plan with a 32-point population."""
-    from repro.core.backends.jax_backend import _fused_eval, _plan_parents
+    roofline pricing, the scheduling sweep, and the makespan, busy and
+    marked finish rows reduced on the device) on a 48-request stream plan
+    with a 32-point population."""
+    from repro.core.backends.jax_backend import (_busy_chunks, _fused_eval,
+                                                 _plan_parents)
+    from repro.core.workload import wave_mark_uids
     from repro.core.scenario import RequestStreamScenario
     from repro.core.simulator import plan_duration_tables
     from repro.core.systems import system_env
@@ -125,7 +128,8 @@ def test_fused_eval_request_stream_x64(one_chip):
     with jax.enable_x64(True):
         tabs = {k: _sds(np.shape(v), np.asarray(v).dtype, one_chip)
                 for k, v in tables.items()}
-        parents = _plan_parents(trace, plan)
-        compiled = _fused_eval(plan).lower(
-            tabs, _sds(parents.shape, parents.dtype, one_chip)).compile()
+        statics = (_plan_parents(trace, plan), *_busy_chunks(plan),
+                   wave_mark_uids(trace).astype(np.int32))
+        compiled = _fused_eval(plan, False).lower(
+            tabs, *(_sds(a.shape, a.dtype, one_chip) for a in statics)).compile()
     assert compiled.memory_analysis() is not None
