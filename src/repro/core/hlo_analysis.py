@@ -127,6 +127,9 @@ class CostTotals:
 
 
 _COMP_HEADER = re.compile(r"^(?:ENTRY\s+)?%?([\w\.\-]+)\s*\(")
+# a shape's layout, e.g. ``{2,1,0:T(8,128)(2,1)S(1)}`` after ``bf16[8,128]``
+# (TPU tiling and memory space): dropped, as it holds no size
+_LAYOUT_RE = re.compile(r"\]\{[^{}]*\}")
 _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w\.\-]+)\s*=\s*((?:\([^)]*\)|[\w\[\],\{\}\d]+?))\s+([\w\-]+)\((.*)$"
 )
@@ -136,7 +139,7 @@ def parse_hlo(text: str) -> dict[str, Computation]:
     comps: dict[str, Computation] = {}
     cur: Computation | None = None
     for raw in text.splitlines():
-        line = raw.rstrip()
+        line = _LAYOUT_RE.sub("]", raw.rstrip())
         if not line:
             continue
         stripped = line.strip()
@@ -315,6 +318,14 @@ class HloCostModel:
                         total.add(self.analyze(sub), 1.0)
                         break  # cost one branch
                 total.bytes_accessed += _shape_bytes(ins.type_str)
+            elif op == "fusion" and (_attr_comp(ins, "calls") or "").startswith(
+                    "all-reduce-scatter"):
+                # the TPU's reduce-scatter: an all-reduce fused with the
+                # slice each device keeps, which is the fusion's output
+                inner = self.comps.get(_attr_comp(ins, "calls"))
+                self._collective(total, "reduce-scatter", ins, next(
+                    (i for i in inner.instructions if i.opcode == "all-reduce"),
+                    None) if inner else None)
             elif op in ("call", "fusion", "async-start"):
                 sub = _attr_comp(ins, "to_apply") or _attr_comp(ins, "calls")
                 if sub:
@@ -332,17 +343,15 @@ class HloCostModel:
                 total.flops += max(inner_flops, 1.0) * elems
                 total.bytes_accessed += self._call_site_bytes(comp, ins)
                 total.bytes_fused += self._call_site_bytes(comp, ins)
-            elif op.startswith("all-") or op in ("reduce-scatter", "collective-permute", "collective-broadcast"):
-                kind = op.replace("-start", "")
-                if kind.endswith("-done"):
-                    continue
-                b = _shape_bytes(ins.type_str)
-                gsz = self._group_size(ins)
-                total.collective_bytes[kind] += b
-                total.collective_counts[kind] += 1
-                total.collective_by_group[(kind, gsz)] += b
-                total.bytes_accessed += b
-                total.bytes_fused += b
+            elif op.removesuffix("-start").removesuffix("-done") in COLLECTIVE_OPS:
+                # an asynchronous pair counts once, at its ``-done``, whose
+                # type is the output alone (a ``-start``'s may hold the input)
+                if op.endswith("-done"):
+                    kind = op.removesuffix("-done")
+                    start = comp.by_name.get(ins.operands[0]) if ins.operands else None
+                    self._collective(total, kind, ins, start)
+                elif not op.endswith("-start"):
+                    self._collective(total, op, ins, ins)
             elif op == "dot":
                 total.flops += _dot_flops(ins, comp)
                 total.bytes_accessed += self._call_site_bytes(comp, ins)
@@ -371,6 +380,18 @@ class HloCostModel:
                 else:
                     total.flops += elems
         return total
+
+    def _collective(self, total: CostTotals, kind: str, ins: Instruction,
+                    attrs_of: Instruction | None) -> None:
+        """One collective of ``kind`` moving ``ins``'s output; its replica
+        groups are read from ``attrs_of``."""
+        b = _shape_bytes(ins.type_str)
+        gsz = self._group_size(attrs_of) if attrs_of is not None else 1
+        total.collective_bytes[kind] += b
+        total.collective_counts[kind] += 1
+        total.collective_by_group[(kind, gsz)] += b
+        total.bytes_accessed += b
+        total.bytes_fused += b
 
     def _slice_bytes(self, comp: Computation, ins: Instruction) -> float:
         if ins.opcode == "dynamic-update-slice" and len(ins.operands) >= 2:

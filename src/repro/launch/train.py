@@ -23,6 +23,7 @@ from jax.sharding import NamedSharding
 
 from repro.configs import ARCHS, get_arch, reduced
 from repro.ckpt.checkpoint import AsyncCheckpointer, latest_step, restore
+from repro.core.hlo_analysis import analyze_compiled_text
 from repro.data.pipeline import DataConfig, Prefetcher, SyntheticLM
 from repro.launch.mesh import make_mesh
 from repro.parallel.sharding import NULL_PLAN, plan_for_mesh, tree_shardings
@@ -30,15 +31,35 @@ from repro.runtime import spans
 from repro.runtime.compile_cache import use_compile_cache
 from repro.runtime.fault import Heartbeat, StragglerMonitor
 from repro.train import optimizer as opt
-from repro.train.train_step import (RunConfig, batch_axes, init_train_state,
-                                    make_train_step, train_state_axes)
+from repro.train.train_step import (RunConfig, batch_abstract, batch_axes,
+                                    init_train_state, make_train_step,
+                                    train_state_axes)
 
 
-def build(spec, mesh, cfg: RunConfig, seed: int = 0):
-    """The jitted step, the initial state and the batch shardings for
-    ``mesh`` (None: the default device, and shardings None).  The state is
-    initialised inside one jitted call whose outputs are already sharded, so
-    no device ever holds more than its own shards of it."""
+class CompiledStep:
+    """The step compiled once, ahead of the loop, for a mesh.  Each call
+    runs that executable and counts, in the open ``repro.train.step`` row,
+    the bytes its collectives move: ``collectives`` holds the compiled HLO's
+    totals by kind (``core/hlo_analysis``, through the layer scan's trip
+    count), each collective's output bytes on one device."""
+
+    def __init__(self, compiled) -> None:
+        self.compiled = compiled
+        self.collectives = analyze_compiled_text(compiled.as_text())
+        self.collective_bytes = self.collectives.total_collective_bytes()
+
+    def __call__(self, state, batch):
+        spans.count("repro.train.collective_bytes", self.collective_bytes)
+        return self.compiled(state, batch)
+
+
+def build(spec, mesh, cfg: RunConfig, seed: int = 0, batch=None):
+    """The step, the initial state and the batch shardings for ``mesh``
+    (None: the default device, a jitted step, and shardings None).  The
+    state is initialised inside one jitted call whose outputs are already
+    sharded, so no device ever holds more than its own shards of it.  Under
+    a mesh the step is a ``CompiledStep``, lowered for the abstract
+    ``batch`` (``batch_abstract``'s tree) and compiled here."""
     plan = plan_for_mesh(mesh) if mesh is not None else NULL_PLAN
     step_fn = make_train_step(spec, plan, cfg)
     init = functools.partial(init_train_state, spec=spec, cfg=cfg)
@@ -54,7 +75,12 @@ def build(spec, mesh, cfg: RunConfig, seed: int = 0):
         batch_sh = {k: NamedSharding(mesh, plan.spec(a))
                     for k, a in batch_axes(spec).items()}
     state = jax.jit(init, out_shardings=state_sh)(rng)
-    return jax.jit(step_fn, donate_argnums=(0,)), state, batch_sh
+    step = jax.jit(step_fn, donate_argnums=(0,))
+    if mesh is not None:
+        laid = {k: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=batch_sh[k])
+                for k, a in batch.items()}
+        step = CompiledStep(step.lower(state, laid).compile())
+    return step, state, batch_sh
 
 
 @dataclass
@@ -84,12 +110,17 @@ def train_loop(args, spec, fail_at: int | None = None) -> TrainRun:
     # the model's sharding constraints name mesh axes: they resolve
     # against the mesh in scope while the step is traced
     with jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext():
-        jit_step, state, batch_sh = build(spec, mesh, cfg, args.seed)
+        # the pipeline's batches: int32 token ids, or float32 embeddings
+        jit_step, state, batch_sh = build(
+            spec, mesh, cfg, args.seed,
+            batch=batch_abstract(spec, args.batch, args.seq, jnp.float32))
 
         ckpt = AsyncCheckpointer(args.ckpt_dir, keep=2) if args.ckpt_dir else None
         start = 0
         if ckpt and latest_step(args.ckpt_dir) is not None:
-            state, start = restore(args.ckpt_dir, state)
+            # back into the layout the step was compiled for
+            state, start = restore(args.ckpt_dir, state, shardings=jax.tree.map(
+                lambda a: a.sharding, state) if mesh is not None else None)
             print(f"[train] resumed from step {start}", flush=True)
 
         data = SyntheticLM(spec, DataConfig(args.batch, args.seq, seed=args.seed))

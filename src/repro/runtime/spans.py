@@ -55,6 +55,8 @@ COUNTERS: dict[str, str] = {
     "repro.engine.points": "repro.engine.generation",   # points handed in
     "repro.engine.evaluated": "repro.engine.generation",  # evaluation memo misses
     "repro.engine.copy_back_bytes": "repro.engine.generation",  # device to host
+    # bytes the compiled step's collectives move (a step over a mesh)
+    "repro.train.collective_bytes": "repro.train.step",
 }
 CAPACITY = 65_536
 

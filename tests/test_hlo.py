@@ -116,3 +116,43 @@ def test_parse_handles_tuple_params():
     comps = parse_hlo(SHARDED_SNIPPET)
     assert set(comps) >= {"body", "cond", "main"}
     assert any(i.opcode == "while" for i in comps["main"].instructions)
+
+
+# the TPU's text: tiled layouts after every shape, asynchronous pairs, and a
+# reduce-scatter written as an all-reduce fused with each device's slice
+TPU_SNIPPET = """\
+HloModule tpu
+
+%add (x: bf16[], y: bf16[]) -> bf16[] {
+  %x = bf16[] parameter(0)
+  %y = bf16[] parameter(1)
+  ROOT %s = bf16[] add(%x, %y)
+}
+
+%all-reduce-scatter.1 (input: bf16[8,128]) -> bf16[4,128] {
+  %input = bf16[8,128]{1,0:T(8,128)(2,1)} parameter(0)
+  %ar = bf16[8,128]{1,0:T(8,128)(2,1)} all-reduce(%input), channel_id=3, replica_groups={{0,1},{2,3}}, to_apply=%add
+  %i = u32[]{:T(128)} partition-id()
+  %z = u32[] constant(0)
+  ROOT %ds = bf16[4,128]{1,0:T(8,128)(2,1)S(1)} dynamic-slice(%ar, %i, %z), dynamic_slice_sizes={4,128}
+}
+
+ENTRY %main (a: bf16[8,128]) -> bf16[4,128] {
+  %a = bf16[8,128]{1,0:T(8,128)(2,1)} parameter(0)
+  %ag = bf16[16,128]{1,0:T(8,128)(2,1)S(1)} all-gather(%a), channel_id=1, replica_groups=[2,2]<=[4], dimensions={0}
+  %cps = (bf16[8,128]{1,0:T(8,128)(2,1)}, bf16[8,128]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%a), channel_id=2, source_target_pairs={{0,1},{1,0}}
+  %cpd = bf16[8,128]{1,0:T(8,128)(2,1)} collective-permute-done(%cps)
+  ROOT %rs = bf16[4,128]{1,0:T(8,128)(2,1)} fusion(%cpd), kind=kCustom, calls=%all-reduce-scatter.1
+}
+"""
+
+
+def test_tpu_layouts_async_pairs_and_fused_reduce_scatter():
+    t = HloCostModel(TPU_SNIPPET).analyze()
+    assert dict(t.collective_counts) == {"all-gather": 1, "collective-permute": 1,
+                                        "reduce-scatter": 1}
+    assert t.collective_bytes["all-gather"] == 16 * 128 * 2
+    assert t.collective_bytes["collective-permute"] == 8 * 128 * 2   # output once
+    assert t.collective_bytes["reduce-scatter"] == 4 * 128 * 2       # the slice kept
+    assert t.collective_by_group[("all-gather", 2)] == 16 * 128 * 2
+    assert t.collective_by_group[("reduce-scatter", 2)] == 4 * 128 * 2
