@@ -41,15 +41,19 @@ class CompiledStep:
     runs that executable and counts, in the open ``repro.train.step`` row,
     the bytes its collectives move: ``collectives`` holds the compiled HLO's
     totals by kind (``core/hlo_analysis``, through the layer scan's trip
-    count), each collective's output bytes on one device."""
+    count), each collective's output bytes on one device.  The all-to-alls'
+    share is counted on its own as well."""
 
     def __init__(self, compiled) -> None:
         self.compiled = compiled
         self.collectives = analyze_compiled_text(compiled.as_text())
         self.collective_bytes = self.collectives.total_collective_bytes()
+        self.all_to_all_bytes = self.collectives.collective_bytes.get(
+            "all-to-all", 0.0)
 
     def __call__(self, state, batch):
         spans.count("repro.train.collective_bytes", self.collective_bytes)
+        spans.count("repro.train.all_to_all_bytes", self.all_to_all_bytes)
         return self.compiled(state, batch)
 
 

@@ -160,6 +160,11 @@ def attention_fwd(p, x, positions, spec: ArchSpec, plan: ShardingPlan,
     else:
         o = flash_attention_ref(q, k, v, positions, window=window,
                                 kv_chunk=kv_chunk, scale=scale)
+    if head_sharded:
+        # keep the output on heads: its cotangent then arrives head-sharded,
+        # and the backward's seq-to-heads reshard moves dO, (B,S,H,hd), not
+        # the (B,H,S,S) scores and probabilities
+        o = plan.constrain(o, ("batch", None, "q_heads", None))
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(x.dtype))
 
 

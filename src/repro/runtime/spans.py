@@ -57,6 +57,8 @@ COUNTERS: dict[str, str] = {
     "repro.engine.copy_back_bytes": "repro.engine.generation",  # device to host
     # bytes the compiled step's collectives move (a step over a mesh)
     "repro.train.collective_bytes": "repro.train.step",
+    # of those, the all-to-alls' bytes
+    "repro.train.all_to_all_bytes": "repro.train.step",
 }
 CAPACITY = 65_536
 

@@ -1,5 +1,6 @@
-"""The training step's collective counter, ``repro.train.collective_bytes``:
-over a 2x2 mesh each step counts what the compiled HLO's collectives move,
+"""The training step's collective counters, ``repro.train.collective_bytes``
+and ``repro.train.all_to_all_bytes``: over a 2x2 mesh each step counts what
+the compiled HLO's collectives (and, of them, its all-to-alls) move,
 read from the very executable the loop calls, which ``build`` compiled once;
 on one device the counter reads 0 and the loop compiles as it did before."""
 from __future__ import annotations
@@ -41,6 +42,9 @@ print(json.dumps({
     "counted": rows["repro.train.collective_bytes"].tolist(),
     "analyzed": None if compiled is None else
         analyze_compiled_text(compiled.as_text()).total_collective_bytes(),
+    "a2a_counted": rows["repro.train.all_to_all_bytes"].tolist(),
+    "a2a_analyzed": None if compiled is None else analyze_compiled_text(
+        compiled.as_text()).collective_bytes.get("all-to-all", 0.0),
     "kinds": {} if compiled is None else dict(step.collectives.collective_counts),
     "compiles": len(compiles), "compiles_after_build": len(compiles) - after_build,
     "losses": run.losses}))
@@ -78,3 +82,17 @@ def test_one_device_counts_nothing_and_compiles_in_its_first_step(loops):
     assert one["compiles_after_build"] >= 1      # jit compiles at the first call
     # the same model, data and seed: one device and the mesh agree
     assert one["losses"] == pytest.approx(loops["2x2"]["losses"], rel=1e-3)
+
+
+def test_mesh_step_counts_its_compiled_all_to_all_bytes(loops):
+    got = loops["2x2"]
+    a2a = got["a2a_analyzed"]
+    assert got["a2a_counted"] == [a2a] * 3
+    assert 0 <= a2a <= got["analyzed"]
+    assert (a2a > 0) == (got["kinds"].get("all-to-all", 0) > 0)
+
+
+def test_one_device_counts_no_all_to_all_bytes(loops):
+    one = loops[""]
+    assert one["a2a_analyzed"] is None
+    assert one["a2a_counted"] == [0.0, 0.0, 0.0]
