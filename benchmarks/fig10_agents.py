@@ -43,7 +43,7 @@ def dse_throughput(steps: int = 500, arch: str = "gpt3-13b") -> tuple[float, flo
     return seq, batched
 
 
-BACKEND_ROW_ORDER = ("reference", "jax-unfused", "jax")
+BACKEND_ROW_ORDER = ("reference", "jax")
 
 
 def backend_population(points: int = 32, n_requests: int = 256, seed: int = 0):
@@ -79,17 +79,16 @@ def backend_population(points: int = 32, n_requests: int = 256, seed: int = 0):
 
 def backend_throughput(points: int = 32, n_requests: int = 256,
                        repeats: int = 3) -> "list[dict] | None":
-    """Points/sec per simulation backend (reference / jax-unfused / jax)
-    evaluating one agent population of collective/network stacks over a
-    LARGE pipelined request-stream trace (``backend_population``) — the
-    acceptance measurement for the backend API and the fused-evaluation
-    path.  All rows run through ``CosmicEnv.step_batch`` (the PR-1 batched
-    engine); the jax rows swap the per-point heapq event loop for one
-    shared-plan ``simulate_batch`` sweep, and the fused ``jax`` row
-    additionally prices all durations inside the same compiled call.  Each
-    row carries the backend's duration-pass vs compiled-sweep wall split
-    (``last_timings``) so the bottleneck claim stays measurable.  None when
-    jax is unavailable."""
+    """Points/sec per simulation backend (reference / jax) evaluating one
+    agent population of collective/network stacks over a LARGE pipelined
+    request-stream trace (``backend_population``) — the acceptance
+    measurement for the backend API and the fused-evaluation path.  Both
+    rows run through ``CosmicEnv.step_batch`` (the batched engine); the
+    ``jax`` row swaps the per-point heapq event loop for one
+    shared-plan ``simulate_batch`` call that prices all durations and
+    sweeps the schedule together.  Each row carries the backend's pack vs
+    compiled-call wall split (``last_timings``) so the bottleneck claim
+    stays measurable.  None when jax is unavailable."""
     from repro.core.backends import backend_available, get_backend
 
     if not backend_available("jax"):
@@ -187,10 +186,8 @@ def backend_rows(points: int = 32, n_requests: int = 256) -> list[tuple]:
     by = {r["backend"]: r["pts_per_s"] for r in bt}
     rows.append(("backend_throughput", 0.0,
                  f"ref_pts_per_s={by['reference']:.1f} "
-                 f"jax_pts_per_s={by['jax-unfused']:.1f} "
                  f"fused_pts_per_s={by['jax']:.1f} "
-                 f"fused_vs_ref=x{by['jax'] / max(by['reference'], 1e-9):.2f} "
-                 f"fused_vs_jax=x{by['jax'] / max(by['jax-unfused'], 1e-9):.2f}"))
+                 f"fused_vs_ref=x{by['jax'] / max(by['reference'], 1e-9):.2f}"))
     rows.extend(verify_overhead_rows(n_requests=n_requests))
     return rows
 

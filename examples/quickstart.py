@@ -15,6 +15,7 @@ from repro.core.compute import SYSTEM_1_DEVICE
 from repro.core.dse import run_search
 from repro.core.env import CosmicEnv
 from repro.core.psa import paper_psa
+from repro.core.scenario import TrainScenario
 from repro.core.workload import Parallelism
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.train.train_step import RunConfig, init_train_state, make_train_step
@@ -24,7 +25,7 @@ def main():
     # -- 1. agent-based full-stack DSE (paper Sections 4-6) ---------------
     spec = ARCHS["gpt3-13b"]
     env = CosmicEnv(spec=spec, n_npus=512, device=SYSTEM_1_DEVICE,
-                    batch=512, seq=2048)
+                    scenario=TrainScenario(512, 2048))
     res = run_search(paper_psa(512), env, "ga", steps=300, seed=0)
     cfg = res.best_config
     print(f"[dse] best reward {res.best_reward:.3e} "

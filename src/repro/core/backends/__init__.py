@@ -4,13 +4,10 @@ Built-ins:
 
   ``reference``  the discrete-event heapq loop — the semantics oracle,
                  bit-identical to the pre-backend ``simulate()``.
-  ``jax``        jit+vmap-compiled levelized DAG sweep FUSED with the
+  ``jax``        jit+vmap-compiled levelized DAG sweep fused with the
                  batched duration pass — an entire population's collective
                  pricing, roofline and schedule evaluate in one compiled
                  call (requires the ``jax`` optional extra).
-  ``jax-unfused`` the same compiled sweep fed by the scalar per-call
-                 duration pass — the pre-fusion baseline, kept so the
-                 duration-pass-vs-sweep split stays measurable.
 """
 from __future__ import annotations
 
@@ -26,27 +23,22 @@ def _reference_factory() -> SimBackend:
     return ReferenceBackend()
 
 
-def _jax_factory(fused: bool = True):
-    def factory() -> SimBackend:
-        try:
-            from repro.core.backends.jax_backend import JaxBackend
-        except ImportError as e:
-            raise ImportError(
-                "the 'jax' simulation backend needs jax installed — "
-                "pip install 'cosmic-repro[jax]'") from e
-        return JaxBackend(fused=fused)
-    return factory
+def _jax_factory() -> SimBackend:
+    try:
+        from repro.core.backends.jax_backend import JaxBackend
+    except ImportError as e:
+        raise ImportError(
+            "the 'jax' simulation backend needs jax installed — "
+            "pip install 'cosmic-repro[jax]'") from e
+    return JaxBackend()
 
 
 register_backend("reference", _reference_factory,
                  doc="discrete-event heapq loop (bit-exact oracle, default)")
-register_backend("jax", _jax_factory(fused=True),
+register_backend("jax", _jax_factory,
                  doc="fused jit+vmap evaluation — vectorized collective + "
                      "roofline pricing and the levelized DAG sweep in one "
                      "compiled call (needs the jax extra)")
-register_backend("jax-unfused", _jax_factory(fused=False),
-                 doc="jit+vmap levelized DAG sweep fed by the scalar "
-                     "per-call duration pass (pre-fusion baseline)")
 
 __all__ = [
     "BACKEND_REGISTRY", "SimBackend", "SimCall", "SimJob",

@@ -1,5 +1,6 @@
-"""JaxBackend: a jit+vmap-compiled levelized sweep over the dependency DAG,
-optionally fused with the batched duration pass into one compiled call.
+"""JaxBackend: one jit+vmap-compiled call per plan that prices every
+op's duration for a whole population and sweeps the dependency DAG with
+them.
 
 The reference event loop is inherently sequential per design point.  This
 backend lowers the shared ``_SimPlan`` into a fixed-structure longest-path
@@ -17,41 +18,31 @@ augmented with per-resource chain edges::
 
 The augmented-parent table is static per trace (built once, piggybacked on
 the plan).  The per-design-point durations are the ONLY population-varying
-input, and they come in two flavours:
-
-  * FUSED (default): ``simulator.plan_duration_tables`` packs the whole
-    population's collective dim tables + roofline coefficients host-side
-    (memoized per design-point key), and one jit-compiled function per plan
-    prices every duration class x population member with the vectorized
-    collective evaluator (``collectives.multidim_collective_time_vec``) and
-    feeds the durations straight into the scheduling sweep — no host
-    round-trip between pricing and scheduling.  The same call reduces on
-    the device what the host reads: each member's makespan, its busy time
-    per resource, and the finish times of the trace's marked uids
-    (``workload.wave_mark_uids``) where a call records finish times — a
-    (1 + n_res + k, P) array instead of two (n_ops, P) matrices.  The full
-    duration and finish matrices come back only for a batch with a
-    ``record_per_op`` call, or with a ``record_finish`` call on a trace
-    that marks no waves.
-  * UNFUSED (``JaxBackend(fused=False)``, registered as ``jax-unfused``):
-    the scalar per-call duration pass (vectorized roofline + memoized
-    scalar collective model via ``simulator.plan_durations``) feeding the
-    compiled sweep — the pre-fusion behaviour, kept as the measurable
-    baseline for the duration-pass-vs-sweep time split.
+input: ``simulator.plan_duration_tables`` packs the whole population's
+collective dim tables + roofline coefficients host-side (memoized per
+design-point key), and one jit-compiled function per plan prices every
+duration class x population member with the vectorized collective
+evaluator (``collectives.multidim_collective_time_vec``) and feeds the
+durations straight into the scheduling sweep — no host round-trip between
+pricing and scheduling.  The same call reduces on the device what the host
+reads: each member's makespan, its busy time per resource, and the finish
+times of the trace's marked uids (``workload.wave_mark_uids``) where a call
+records finish times — a (1 + n_res + k, P) array instead of two
+(n_ops, P) matrices.  The full duration and finish matrices come back only
+for a batch with a ``record_per_op`` call, or with a ``record_finish`` call
+on a trace that marks no waves.
 
 Every ``simulate_batch`` runs under the spans of ``repro.runtime.spans``:
-``repro.engine.pack`` (the host-side duration pass: the scalar loop when
-unfused, the memoized table packing when fused), ``repro.engine.dispatch``
-(the compiled call returning), ``repro.engine.device_wait`` (the outputs
-ready), ``repro.engine.copy_back`` (to host numpy; its bytes are the
-counter ``repro.engine.copy_back_bytes``) and ``repro.engine.assemble``
-(the ``SimResult``s, and the busy accounting when unfused).
-``last_timings`` keeps the split of the most recent call, from the same
-span durations: ``durations_s`` is the pack, ``sweep_s`` the dispatch,
-wait and copy back (pricing + sweep together when fused).  On the device
-the fused call's operations sit under the named scopes
-``repro.engine.price``, ``repro.engine.sweep`` and
-``repro.engine.reduce``.
+``repro.engine.pack`` (the memoized table packing),
+``repro.engine.dispatch`` (the compiled call returning),
+``repro.engine.device_wait`` (the outputs ready),
+``repro.engine.copy_back`` (to host numpy; its bytes are the counter
+``repro.engine.copy_back_bytes``) and ``repro.engine.assemble`` (the
+``SimResult``s).  ``last_timings`` keeps the split of the most recent
+call, from the same span durations: ``durations_s`` is the pack,
+``sweep_s`` the dispatch, wait and copy back.  On the device the call's
+operations sit under the named scopes ``repro.engine.price``,
+``repro.engine.sweep`` and ``repro.engine.reduce``.
 
 Fidelity: each resource serializes its ops in issue order instead of the
 reference loop's arrival-order (FIFO) / freshest-first (LIFO) queue
@@ -74,7 +65,7 @@ from jax import lax
 
 from repro.core.simulator import (SimResult, SystemConfig, _SimPlan,
                                   batch_op_durations, build_sim_result,
-                                  plan_duration_tables, plan_durations)
+                                  plan_duration_tables)
 from repro.core.workload import Parallelism, Trace, wave_mark_uids
 from repro.runtime import spans
 
@@ -263,20 +254,15 @@ class FinishTimes(Mapping):
 
 
 class JaxBackend:
-    """Population-vectorized scheduling on the XLA-compiled levelized sweep.
+    """Population-vectorized scheduling: durations priced inside the same
+    compiled call as the sweep (registered as ``jax``)."""
 
-    ``fused=True`` (the default, registered as ``jax``) prices durations
-    inside the same compiled call as the sweep; ``fused=False`` (registered
-    as ``jax-unfused``) keeps the scalar per-call duration pass feeding the
-    sweep — the measurable pre-fusion baseline."""
-
+    name = "jax"
     vectorized = True
 
-    def __init__(self, fused: bool = True) -> None:
-        self.fused = fused
-        self.name = "jax" if fused else "jax-unfused"
-        # duration-pass vs compiled-evaluation wall-time split of the most
-        # recent simulate_batch, from its spans (see module docstring)
+    def __init__(self) -> None:
+        # pack vs compiled-evaluation wall-time split of the most recent
+        # simulate_batch, from its spans (see module docstring)
         self.last_timings: dict[str, float] = {}
 
     def simulate(self, trace: Trace, cfg: SystemConfig, par: Parallelism, *,
@@ -294,70 +280,38 @@ class JaxBackend:
                        calls: Sequence[Any]) -> list[SimResult]:
         if not calls:
             return []
-        if self.fused:
-            # the full matrices only where a call asks for every op, or for
-            # finish times on a trace that marks no waves; otherwise the
-            # makespan, the busy time and the marked uids' finish times
-            wants = any(c.record_finish for c in calls)
-            marked = wants and bool(trace.meta.get("wave_marks"))
-            full = any(c.record_per_op for c in calls) or (wants and not marked)
-            with spans.span("repro.engine.pack") as pack:
-                plan, tables = plan_duration_tables(trace, calls)
-            # double precision scoped to the sweep (the global default stays
-            # f32 for the model and kernel code paths)
-            with jax.enable_x64(True):
-                with spans.span("repro.engine.dispatch") as dispatch:
-                    uids, uids_d = (_plan_marks(trace, plan)
-                                    if marked and not full else (None, None))
-                    got = _fused_eval(plan, full)(
-                        tables, *_plan_statics(trace, plan), uids_d)
-                with spans.span("repro.engine.device_wait") as wait:
-                    jax.block_until_ready(got)
-                with spans.span("repro.engine.copy_back") as copy:
-                    host = [np.asarray(a) for a in (got if full else (got,))]
-            copied = sum(a.nbytes for a in host)
-        else:
-            full = True        # the unfused sweep returns every finish time
-            with spans.span("repro.engine.pack") as pack:
-                plans_durs = [plan_durations(trace, c.cfg, c.par, c.pools)
-                              for c in calls]
-                plan = plans_durs[0][0]
-                parents = _plan_parents(trace, plan)
-                dur = np.asarray([d for _, d in plans_durs], dtype=np.float64)
-            with jax.enable_x64(True):
-                with spans.span("repro.engine.dispatch") as dispatch:
-                    finish_d = _sweep_population(jnp.asarray(dur.T),
-                                                 jnp.asarray(parents))
-                with spans.span("repro.engine.device_wait") as wait:
-                    finish_d.block_until_ready()
-                with spans.span("repro.engine.copy_back") as copy:
-                    finish_all = np.asarray(finish_d)
-                    finish = finish_all[:plan.n_ops].T
-            copied = finish_all.nbytes
-        spans.count("repro.engine.copy_back_bytes", copied)
+        # the full matrices only where a call asks for every op, or for
+        # finish times on a trace that marks no waves; otherwise the
+        # makespan, the busy time and the marked uids' finish times
+        wants = any(c.record_finish for c in calls)
+        marked = wants and bool(trace.meta.get("wave_marks"))
+        full = any(c.record_per_op for c in calls) or (wants and not marked)
+        with spans.span("repro.engine.pack") as pack:
+            plan, tables = plan_duration_tables(trace, calls)
+        # double precision scoped to the sweep (the global default stays
+        # f32 for the model and kernel code paths)
+        with jax.enable_x64(True):
+            with spans.span("repro.engine.dispatch") as dispatch:
+                uids, uids_d = (_plan_marks(trace, plan)
+                                if marked and not full else (None, None))
+                got = _fused_eval(plan, full)(
+                    tables, *_plan_statics(trace, plan), uids_d)
+            with spans.span("repro.engine.device_wait") as wait:
+                jax.block_until_ready(got)
+            with spans.span("repro.engine.copy_back") as copy:
+                host = [np.asarray(a) for a in (got if full else (got,))]
+        spans.count("repro.engine.copy_back_bytes", sum(a.nbytes for a in host))
         self.last_timings = {
             "durations_s": pack.seconds,
             "sweep_s": dispatch.seconds + wait.seconds + copy.seconds}
         with spans.span("repro.engine.assemble"):
             n_res = len(plan.res_names)
-            if self.fused:
-                # (P, 1 + n_res + k) views: makespan, busy, marked finish
-                cols = host[0].T
-                makespan, busy2d = cols[:, 0], cols[:, 1:1 + n_res]
-                if full:
-                    dur = host[1].T                      # (P, n_ops) views
-                    finish = host[2][:plan.n_ops].T
-            else:
-                makespan = (finish.max(axis=1) if plan.n_ops
-                            else np.zeros(len(calls)))
-                # whole-population busy accounting in one 2D scatter over
-                # (population, resource): each (member, resource) cell
-                # accumulates in increasing-uid order, as the per-call
-                # np.bincount it replaces
-                res_of = np.asarray(plan.res_of, dtype=np.intp)
-                busy2d = np.zeros((len(calls), n_res), dtype=np.float64)
-                np.add.at(busy2d,
-                          (np.arange(len(calls))[:, None], res_of[None, :]), dur)
+            # (P, 1 + n_res + k) views: makespan, busy, marked finish
+            cols = host[0].T
+            makespan, busy2d = cols[:, 0], cols[:, 1:1 + n_res]
+            if full:
+                dur = host[1].T                          # (P, n_ops) views
+                finish = host[2][:plan.n_ops].T
             out: list[SimResult] = []
             for k, call in enumerate(calls):
                 fin: Mapping = {}

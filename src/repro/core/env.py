@@ -2,9 +2,11 @@
 
 An agent submits a PsA configuration; the environment materializes the
 (workload, collective, network, compute) stacks and hands the resolved
-``EnvContext`` to its ``Scenario``, which runs the WTG + simulator and
-returns the reward.  Fixed parameters (single-stack baselines) are handled
-upstream by ``ParameterSet.restrict`` — the env is stack-agnostic.
+``EnvContext`` to its ``Scenario``, whose ``sim_job`` describes the
+design point's simulator calls; the env runs them on its backend and the
+job's finalize returns the reward.  Fixed parameters (single-stack
+baselines) are handled upstream by ``ParameterSet.restrict`` — the env is
+stack-agnostic.
 
 Batched evaluation: ``step_batch`` evaluates a population of configurations
 at once, deduplicating repeated design points through a per-env evaluation
@@ -12,9 +14,8 @@ memo (evaluation is a pure function of the config) and optionally fanning
 the distinct points out to a ``concurrent.futures`` process pool.  Results
 are identical to serial ``step`` calls in the same order.  With a
 vectorized simulation backend (``backend="jax"``), the surviving unique
-points are instead described as declarative ``SimJob``s and swept through
-the backend's population-batched ``simulate_batch``, grouped by shared
-trace.
+points' ``SimJob``s are instead swept through the backend's
+population-batched ``simulate_batch``, grouped by shared trace.
 
 Cross-search sharing: pass the same ``eval_store`` dict to several envs
 over the same (spec, scenario, system) and they share one evaluation memo —
@@ -33,11 +34,12 @@ from dataclasses import dataclass, field, replace
 from typing import Any, Sequence
 
 from repro.configs.base import ArchSpec
-from repro.core.backends import BACKEND_REGISTRY, get_backend, run_sim_jobs
+from repro.core.backends import (BACKEND_REGISTRY, get_backend, run_sim_job,
+                                 run_sim_jobs)
 from repro.core.cache import cache_epoch, caches_enabled
 from repro.core.compute import Device
 from repro.core.rewards import Evaluation, Objective, get_objective
-from repro.core.scenario import EnvContext, Scenario, TrainScenario
+from repro.core.scenario import EnvContext, Scenario
 from repro.core.simulator import SystemConfig
 from repro.core.topology import Network, build_network
 from repro.runtime import spans
@@ -93,14 +95,8 @@ class CosmicEnv:
     spec: ArchSpec
     n_npus: int
     device: Device
-    # the workload shape under design.  Either pass a Scenario, or use the
-    # legacy (batch, seq, mode, decode_tokens) fields and get a TrainScenario
-    # built for you — PR-1 call sites keep working unchanged.
-    scenario: Scenario | None = None
-    batch: int | None = None
-    seq: int | None = None
-    mode: str | None = "train"
-    decode_tokens: int | None = 64
+    # the workload shape under design
+    scenario: Scenario
     # an Objective-registry name or an Objective instance; resolved to an
     # Objective at construction (self.objective is always an Objective after
     # __post_init__)
@@ -135,9 +131,8 @@ class CosmicEnv:
         # objectives (e.g. "goodput") additionally need a scenario that
         # resolves per-request metrics itself
         self.objective = get_objective(self.objective)
-        if self.objective.streaming and self.scenario is not None \
-                and not getattr(self.scenario, "supports_stream_objectives",
-                                False):
+        if self.objective.streaming and not getattr(
+                self.scenario, "supports_stream_objectives", False):
             raise ValueError(
                 f"objective {self.objective.name!r} needs a streaming "
                 f"scenario (per-request metrics); "
@@ -146,20 +141,6 @@ class CosmicEnv:
         if self.backend not in BACKEND_REGISTRY:
             raise ValueError(f"unknown simulation backend {self.backend!r}; "
                              f"known: {sorted(BACKEND_REGISTRY)}")
-        if self.scenario is None:
-            if self.objective.streaming:
-                raise ValueError(f"objective {self.objective.name!r} needs a "
-                                 f"streaming scenario, not the legacy "
-                                 f"batch/seq TrainScenario path")
-            if self.batch is None or self.seq is None:
-                raise TypeError("CosmicEnv needs either a scenario or "
-                                "legacy batch/seq fields")
-            self.scenario = TrainScenario(self.batch, self.seq, self.mode,
-                                          self.decode_tokens)
-        else:
-            # the scenario owns the workload shape — drop legacy fields so
-            # nothing reads stale workload metadata off the env
-            self.batch = self.seq = self.mode = self.decode_tokens = None
 
     def _network(self, config: dict[str, Any]) -> Network:
         if self.fixed_network is not None and "topology" not in config:
@@ -184,7 +165,8 @@ class CosmicEnv:
 
     def evaluate_config(self, config: dict[str, Any]) -> Evaluation:
         """Pure evaluation of one design point (no history, no memo)."""
-        return self.scenario.evaluate(self.context(config))
+        return run_sim_job(self.scenario.sim_job(self.context(config)),
+                           self.backend)
 
     def clear_memo(self) -> None:
         self._eval_cache.clear()
@@ -315,8 +297,7 @@ class CosmicEnv:
     def _eval_many(self, cfgs: list[dict[str, Any]],
                    workers: int) -> list[Evaluation]:
         backend = get_backend(self.backend)
-        if backend.vectorized and len(cfgs) > 1 \
-                and hasattr(self.scenario, "sim_job"):
+        if backend.vectorized and len(cfgs) > 1:
             # population-vectorized path: describe every point's simulator
             # calls declaratively, then sweep the calls sharing a trace —
             # and therefore a scheduling plan — in one simulate_batch each.

@@ -37,7 +37,7 @@ from typing import Any, ClassVar, Mapping
 import numpy as np
 
 from repro.configs.base import ArchSpec
-from repro.core.backends import SimJob, run_sim_job
+from repro.core.backends import SimJob
 from repro.core.cache import switchable_lru_cache
 from repro.core.compute import DEVICES, Device
 from repro.core.psa import Constraint, Parameter
@@ -527,9 +527,6 @@ class FleetScenario:
             })
 
         return SimJob(tuple(call for _, _, call, _, _, _ in slices), fin)
-
-    def evaluate(self, ctx: EnvContext) -> Evaluation:
-        return run_sim_job(self.sim_job(ctx), ctx.backend)
 
 
 register_scenario("fleet", dataclass_scenario_builder(FleetScenario))

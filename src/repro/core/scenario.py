@@ -10,8 +10,9 @@ methods:
     agents alongside the workload/collective/network stacks;
   * ``traces(ctx)`` — the symbolic phase traces behind one design point
     (inspection/debug);
-  * ``evaluate(ctx)`` — design point -> ``Evaluation`` (reward, latency,
-    validity gate), where ``ctx`` is the env-resolved ``EnvContext``.
+  * ``sim_job(ctx)`` — design point -> the ``SimJob`` whose simulator
+    calls and finalize give its ``Evaluation`` (reward, latency, validity
+    gate), where ``ctx`` is the env-resolved ``EnvContext``.
 
 Four built-ins:
 
@@ -45,7 +46,7 @@ from typing import (Any, Callable, ClassVar, Mapping, Protocol,
 import numpy as np
 
 from repro.configs.base import ArchSpec
-from repro.core.backends import SimCall, SimJob, run_sim_job
+from repro.core.backends import SimCall, SimJob
 from repro.core.cache import switchable_lru_cache
 from repro.core.compute import DEVICES, Device
 from repro.core.memory import footprint, kv_cache_bytes
@@ -96,21 +97,19 @@ class Scenario(Protocol):
     """Structural protocol — any frozen, picklable object with these methods
     can drive ``CosmicEnv`` (process-pool workers receive a copy).
 
-    Optional capability: ``sim_job(ctx) -> SimJob | Evaluation`` describes
-    the design point's simulator calls declaratively (see
-    ``repro.core.backends``).  Scenarios that provide it get population-
-    vectorized evaluation for free — ``CosmicEnv.step_batch`` hands the
-    surviving unique configs of a batch to the backend's ``simulate_batch``
-    (grouped by shared trace) instead of looping ``evaluate``.  All four
-    built-ins implement it; ``evaluate`` is then just ``run_sim_job(
-    self.sim_job(ctx), ctx.backend)``."""
+    ``sim_job(ctx) -> SimJob | Evaluation`` describes the design point's
+    simulator calls declaratively (see ``repro.core.backends``), or returns
+    the ``Evaluation`` outright where no simulation is needed (an invalid
+    point).  ``CosmicEnv`` runs one point as ``run_sim_job(sim_job(ctx),
+    backend)`` and a batch's surviving unique points through the backend's
+    ``simulate_batch``, grouped by shared trace."""
 
     name: str
 
     def psa_params(self) -> list[Parameter]: ...
     def psa_constraints(self, n_npus: int) -> list[Constraint]: ...
     def traces(self, ctx: EnvContext) -> dict[str, Trace]: ...
-    def evaluate(self, ctx: EnvContext) -> Evaluation: ...
+    def sim_job(self, ctx: EnvContext) -> "SimJob | Evaluation": ...
 
 
 def scenario_psa(base: ParameterSet, scenario: Scenario,
@@ -166,9 +165,6 @@ class TrainScenario:
                             objective=ctx.objective,
                             capacity_gb=ctx.capacity_gb,
                             decode_tokens=self.decode_tokens)
-
-    def evaluate(self, ctx: EnvContext) -> Evaluation:
-        return run_sim_job(self.sim_job(ctx), ctx.backend)
 
 
 # ---------------------------------------------------------------------------
@@ -519,9 +515,6 @@ class DisaggServeScenario:
                                pools={0: pre_pool, 1: dec_pool}),
                        SimCall(dec_tr, ctx.sys_cfg, par_dec,
                                pools={0: dec_pool})), fin_analytic)
-
-    def evaluate(self, ctx: EnvContext) -> Evaluation:
-        return run_sim_job(self.sim_job(ctx), ctx.backend)
 
 
 # ---------------------------------------------------------------------------
@@ -881,7 +874,7 @@ class RequestStreamScenario:
         or the instant it fills to the admission cap; each ``(indices,
         release_ms)`` becomes one wave of the pipelined trace.
 
-        ``max_batch`` overrides the scenario cap — ``evaluate`` passes the
+        ``max_batch`` overrides the scenario cap — ``sim_job`` passes the
         decode pool's resident capacity (``replicas * decode_batch``, itself
         capped by the scenario ``max_batch``) so an admitted wave never
         exceeds what the decode pool can actually hold.  Memoized per
@@ -1030,9 +1023,6 @@ class RequestStreamScenario:
             })
 
         return SimJob((call,), fin)
-
-    def evaluate(self, ctx: EnvContext) -> Evaluation:
-        return run_sim_job(self.sim_job(ctx), ctx.backend)
 
 
 # ---------------------------------------------------------------------------
@@ -1189,9 +1179,6 @@ class MultiTenantScenario:
             })
 
         return SimJob(tuple(calls), fin)
-
-    def evaluate(self, ctx: EnvContext) -> Evaluation:
-        return run_sim_job(self.sim_job(ctx), ctx.backend)
 
 
 # ---------------------------------------------------------------------------

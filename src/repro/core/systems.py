@@ -115,16 +115,20 @@ def system_env(arch, system: "str | SystemPreset", *, batch: int = 1024,
                eval_store: dict | None = None, decode_tokens: int = 64,
                capacity_gb: float = 24.0, backend: str = "reference"):
     """A ``CosmicEnv`` over a registered system.  ``arch`` is an ``ARCHS``
-    key or an ``ArchSpec``; ``seq`` defaults to the arch's max_seq;
-    ``backend`` selects the simulation backend (``repro.core.backends``)."""
+    key or an ``ArchSpec``; without a ``scenario`` the env runs a
+    ``TrainScenario(batch, seq, mode, decode_tokens)``, ``seq`` defaulting
+    to the arch's max_seq; ``backend`` selects the simulation backend
+    (``repro.core.backends``)."""
     from repro.configs import ARCHS
     from repro.core.env import CosmicEnv
+    from repro.core.scenario import TrainScenario
 
     preset = get_system(system)
     spec = ARCHS[arch] if isinstance(arch, str) else arch
+    if scenario is None:
+        scenario = TrainScenario(batch, seq or spec.max_seq, mode,
+                                 decode_tokens)
     return CosmicEnv(spec=spec, n_npus=preset.n_npus, device=preset.device,
-                     scenario=scenario, batch=batch,
-                     seq=seq or spec.max_seq, mode=mode,
-                     decode_tokens=decode_tokens, objective=objective,
+                     scenario=scenario, objective=objective,
                      eval_store=eval_store, capacity_gb=capacity_gb,
                      backend=backend)

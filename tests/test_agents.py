@@ -10,12 +10,13 @@ from repro.core.compute import SYSTEM_2_DEVICE
 from repro.core.dse import run_search
 from repro.core.env import CosmicEnv
 from repro.core.psa import paper_psa
+from repro.core.scenario import TrainScenario
 from repro.core.space import DesignSpace
 
 
 def _env():
     return CosmicEnv(spec=ARCHS["gpt3-13b"], n_npus=1024, device=SYSTEM_2_DEVICE,
-                     batch=1024, seq=2048)
+                     scenario=TrainScenario(1024, 2048))
 
 
 @pytest.mark.parametrize("kind", ["rw", "ga", "aco", "bo"])

@@ -359,9 +359,6 @@ class _DefectiveScenario:
         call = SimCall(trace, ctx.sys_cfg, ctx.parallelism())
         return SimJob((call,), lambda rs: None)
 
-    def evaluate(self, ctx):
-        return run_sim_job(self.sim_job(ctx), ctx.backend)
-
 
 @pytest.fixture()
 def defective_scenario_kind():

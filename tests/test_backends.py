@@ -15,7 +15,8 @@ from repro.core.compute import SYSTEM_2_DEVICE
 from repro.core.env import CosmicEnv
 from repro.core.psa import paper_psa
 from repro.core.scenario import (DisaggServeScenario, MultiTenantScenario,
-                                 RequestStreamScenario, Tenant, scenario_psa)
+                                 RequestStreamScenario, Tenant,
+                                 TrainScenario, scenario_psa)
 from repro.core.simulator import SystemConfig, simulate
 from repro.core.space import DesignSpace
 from repro.core.systems import system_env
@@ -58,7 +59,7 @@ def test_registry_lists_builtins_and_rejects_unknown():
 def test_env_and_simulate_reject_unknown_backend():
     with pytest.raises(ValueError, match="unknown simulation backend"):
         CosmicEnv(spec=ARCHS["qwen2-1.5b"], n_npus=1024,
-                  device=SYSTEM_2_DEVICE, batch=64, seq=2048,
+                  device=SYSTEM_2_DEVICE, scenario=TrainScenario(64, 2048),
                   backend="not-a-backend")
     par = Parallelism(64, dp=64, sp=1, pp=1)
     tr = generate_trace(ARCHS["qwen2-1.5b"], par, batch=64, seq=128)
@@ -327,16 +328,16 @@ def _wave_calls(name: str) -> list[SimCall]:
 
 
 @pytest.mark.parametrize("name", sorted(WAVE_SCENARIOS))
-def test_jax_fused_matches_unfused_on_wave_traces(name):
+def test_jax_matches_reference_on_wave_traces(name):
     """Makespan and wave times bit for bit, busy to f64 rounding: the
-    reductions moved to the device and nothing else."""
+    device reductions against the reference event loop."""
     from repro.core.scenario import _wave_times_ms
 
     calls = _wave_calls(name)
     tr = calls[0].trace
-    fused = get_backend("jax").simulate_batch(tr, calls)
-    unfused = get_backend("jax-unfused").simulate_batch(tr, calls)
-    for got, want in zip(fused, unfused):
+    jaxed = get_backend("jax").simulate_batch(tr, calls)
+    ref = get_backend("reference").simulate_batch(tr, calls)
+    for got, want in zip(jaxed, ref):
         assert got.makespan_us == want.makespan_us
         assert _wave_times_ms(tr, got) == _wave_times_ms(tr, want)
         assert _rel(got.compute_busy_us, want.compute_busy_us) < RTOL
@@ -351,7 +352,7 @@ def test_jax_record_finish_holds_only_the_marked_uids():
     calls = _wave_calls("stream")
     tr = calls[0].trace
     got = get_backend("jax").simulate_batch(tr, calls)[0].op_finish_us
-    want = get_backend("jax-unfused").simulate_batch(tr, calls)[0].op_finish_us
+    want = get_backend("reference").simulate_batch(tr, calls)[0].op_finish_us
     marked = wave_mark_uids(tr).tolist()
     assert 0 < len(marked) < len(tr.ops) // 4
     assert list(got) == marked and len(got) == len(marked)
@@ -383,7 +384,7 @@ def test_jax_full_matrices_where_every_op_is_asked_for(ask):
                                  if k != "wave_marks"})
         calls = [replace(c, trace=tr) for c in calls]
     got = get_backend("jax").simulate_batch(tr, calls)
-    want = get_backend("jax-unfused").simulate_batch(tr, calls)
+    want = get_backend("reference").simulate_batch(tr, calls)
     n = len(tr.ops)
     assert len(got[0].op_finish_us) == n == len(want[0].op_finish_us)
     assert all(got[0].op_finish_us[u] == want[0].op_finish_us[u]
@@ -396,6 +397,8 @@ def test_jax_full_matrices_where_every_op_is_asked_for(ask):
 
 
 def test_jax_copy_back_bytes_of_a_stream_batch():
+    from dataclasses import replace
+
     from repro.core.simulator import plan_duration_tables
     from repro.core.workload import wave_mark_uids
     from repro.runtime import spans
@@ -408,7 +411,9 @@ def test_jax_copy_back_bytes_of_a_stream_batch():
         get_backend("jax").simulate_batch(tr, calls)
     copied = spans.rows(gen)[-1]["repro.engine.copy_back_bytes"]
     assert 0 < copied <= (len(wave_mark_uids(tr)) + 1 + n_res) * len(calls) * 8
+    # a member that asks for every op brings the full matrices back
     with spans.unit(gen):
-        get_backend("jax-unfused").simulate_batch(tr, calls)
+        get_backend("jax").simulate_batch(
+            tr, [replace(calls[0], record_per_op=True)] + calls[1:])
     assert spans.rows(gen)[-1]["repro.engine.copy_back_bytes"] \
         >= len(tr.ops) * len(calls) * 8

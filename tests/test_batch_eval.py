@@ -2,7 +2,6 @@
 bit-identical memoization layers, and process-pool consistency."""
 from __future__ import annotations
 
-from dataclasses import dataclass
 
 import numpy as np
 import pytest
@@ -24,7 +23,7 @@ from repro.core.workload import Parallelism, _generate_trace_impl, generate_trac
 
 def _env():
     return CosmicEnv(spec=ARCHS["gpt3-13b"], n_npus=1024, device=SYSTEM_2_DEVICE,
-                     batch=1024, seq=2048)
+                     scenario=TrainScenario(1024, 2048))
 
 
 def _sequential_reference(kind: str, steps: int, seed: int):
@@ -150,26 +149,6 @@ def test_step_batch_process_pool_matches_serial(clear_dse_caches):
     assert [r.config for r in pool_env.history] == cfgs
 
 
-@dataclass(frozen=True)
-class _NoJobScenario:
-    """A scenario without ``sim_job``: the env can only evaluate it point by
-    point, so ``workers > 1`` reaches the process-pool branch."""
-    inner: TrainScenario
-    name: str = "no-job"
-
-    def psa_params(self):
-        return self.inner.psa_params()
-
-    def psa_constraints(self, n_npus):
-        return self.inner.psa_constraints(n_npus)
-
-    def traces(self, ctx):
-        return self.inner.traces(ctx)
-
-    def evaluate(self, ctx):
-        return self.inner.evaluate(ctx)
-
-
 def test_jax_backend_never_starts_a_worker(clear_dse_caches):
     """One process per accelerator: a jax-backend env asked for workers
     evaluates in its own process and never creates the pool."""
@@ -179,7 +158,7 @@ def test_jax_backend_never_starts_a_worker(clear_dse_caches):
     serial = [_env().evaluate_config(c) for c in cfgs]
     with CosmicEnv(spec=ARCHS["gpt3-13b"], n_npus=1024,
                    device=SYSTEM_2_DEVICE,
-                   scenario=_NoJobScenario(TrainScenario(1024, 2048)),
+                   scenario=TrainScenario(1024, 2048),
                    backend="jax") as env:
         got = env.step_batch(cfgs, workers=2)
         assert env._executor is None

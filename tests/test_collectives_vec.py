@@ -1,7 +1,8 @@
 """Vectorized-evaluation tests: the array collective evaluator against the
 scalar oracle over the full model grid, the batched whole-population
 duration pass against the scalar per-call pass (bit-identical), the
-sub-network-carving memoization, and the one-scatter busy accounting."""
+sub-network-carving memoization, the device busy sums' row table, and the
+jax backend against the reference event loop."""
 from __future__ import annotations
 
 import numpy as np
@@ -332,30 +333,8 @@ def test_carving_caches_hit_across_population_and_batches():
 
 
 # ---------------------------------------------------------------------------
-# busy accounting: the one-scatter 2D np.add.at == per-call bincount
-# ---------------------------------------------------------------------------
-
-def test_busy_scatter_both_orientations_match_bincount():
-    """Both broadcast orientations of the (population, resource) scatter
-    accumulate each cell in increasing-uid order — exactly the order of the
-    per-call ``np.bincount`` they replaced — so all three are bit-identical
-    even where float addition would not commute."""
-    rng = np.random.default_rng(11)
-    P, n_ops, n_res = 6, 4000, 13
-    dur = rng.uniform(0.0, 1e6, size=(P, n_ops))
-    res_of = rng.integers(0, n_res, size=n_ops)
-    want = np.stack([np.bincount(res_of, weights=dur[k], minlength=n_res)
-                     for k in range(P)])
-    pop_major = np.zeros((P, n_res))
-    np.add.at(pop_major, (np.arange(P)[:, None], res_of[None, :]), dur)
-    op_major = np.zeros((P, n_res))
-    np.add.at(op_major.T, (res_of[:, None], np.arange(P)[None, :]), dur.T)
-    assert np.array_equal(pop_major, want)
-    assert np.array_equal(op_major, want)
-
-
-# ---------------------------------------------------------------------------
-# fused vs unfused backends (jax-guarded, like test_backends)
+# the jax backend against the reference loop (jax-guarded, like
+# test_backends)
 # ---------------------------------------------------------------------------
 
 jax = pytest.importorskip("jax")
@@ -363,32 +342,57 @@ jax = pytest.importorskip("jax")
 from repro.core.backends import get_backend, list_backends  # noqa: E402
 
 
-def test_unfused_backend_registered():
-    assert {"jax", "jax-unfused"} <= set(list_backends())
-    jb, ub = get_backend("jax"), get_backend("jax-unfused")
-    assert jb.fused and jb.name == "jax"
-    assert not ub.fused and ub.name == "jax-unfused"
-    assert jb is not ub
+def test_backend_registry_is_reference_and_jax():
+    assert set(list_backends()) == {"reference", "jax"}
+    assert get_backend("jax").name == "jax"
+    with pytest.raises(ValueError, match="unknown simulation backend"):
+        get_backend("jax-unfused")
+
+
+def test_busy_chunks_hold_each_op_once_in_uid_order():
+    """The device busy sums' row table: every op in exactly one row of its
+    own resource, rows grouped by resource, uid order within a resource,
+    so the row sums scattered per resource give the per-resource busy
+    time."""
+    from repro.core.backends.jax_backend import BUSY_CHUNK, _busy_chunks
+
+    par = Parallelism(1024, 64, 4, 1, True)
+    tr = generate_trace(ARCHS["qwen2-1.5b"], par, batch=256, seq=1024)
+    plan, dur = plan_durations(tr, _cfgs_population()[0], par)
+    chunks, chunk_res = _busy_chunks(plan)
+    n = plan.n_ops
+    res_of = np.asarray(plan.res_of)
+    assert chunks.shape[1] == BUSY_CHUNK
+    assert np.all(np.diff(chunk_res) >= 0)
+    held = chunks[chunks < n]
+    assert sorted(held.tolist()) == list(range(n))
+    for row, r in zip(chunks, chunk_res):
+        ops = row[row < n]
+        assert np.all(res_of[ops] == r) and np.all(np.diff(ops) > 0)
+    part = np.append(dur, 0.0)[chunks].sum(axis=1)
+    busy = np.bincount(chunk_res, weights=part, minlength=len(plan.res_names))
+    want = np.bincount(res_of, weights=dur, minlength=len(plan.res_names))
+    assert np.all(np.abs(busy - want) <= RTOL * np.maximum(np.abs(want), 1e-12))
 
 
 def test_fused_matches_unfused_and_single():
-    """The fused backend (durations priced inside the compiled sweep) and
-    the unfused baseline (scalar duration pass feeding the same sweep)
-    agree to float64 tolerance; each backend's batch == its own single."""
+    """The jax backend (durations priced inside the compiled sweep) agrees
+    with the reference event loop to float64 tolerance, and its batch ==
+    its own single."""
     par = Parallelism(1024, 64, 4, 1, True)
     tr = generate_trace(ARCHS["qwen2-1.5b"], par, batch=256, seq=1024)
     calls = [SimCall(tr, cfg, par) for cfg in _cfgs_population()]
-    fused = get_backend("jax").simulate_batch(tr, calls)
-    unfused = get_backend("jax-unfused").simulate_batch(tr, calls)
+    jb = get_backend("jax")
+    got = jb.simulate_batch(tr, calls)
+    ref = get_backend("reference").simulate_batch(tr, calls)
     for k, call in enumerate(calls):
-        rel = _rel(fused[k].makespan_us, unfused[k].makespan_us)
+        rel = _rel(got[k].makespan_us, ref[k].makespan_us)
         assert float(rel) < RTOL, k
-        one = get_backend("jax-unfused").simulate(tr, call.cfg, call.par)
-        assert unfused[k].makespan_us == one.makespan_us
-        assert unfused[k].comm_busy_us == one.comm_busy_us
-        for res, busy in unfused[k].comm_busy_us.items():
-            assert float(_rel(fused[k].comm_busy_us[res], busy)) < RTOL
-    # the timing split is populated either way (the benchmark reads it)
-    assert set(get_backend("jax").last_timings) == {"durations_s", "sweep_s"}
-    assert set(get_backend("jax-unfused").last_timings) == \
-        {"durations_s", "sweep_s"}
+        one = jb.simulate(tr, call.cfg, call.par)
+        assert got[k].makespan_us == one.makespan_us
+        assert got[k].comm_busy_us == one.comm_busy_us
+        assert got[k].comm_busy_us.keys() == ref[k].comm_busy_us.keys()
+        for res, busy in ref[k].comm_busy_us.items():
+            assert float(_rel(got[k].comm_busy_us[res], busy)) < RTOL
+    # the timing split is populated (the benchmark reads it)
+    assert set(jb.last_timings) == {"durations_s", "sweep_s"}

@@ -28,9 +28,10 @@ from repro.core.workload import (Op, Parallelism, Trace, TraceBuilder,
 SPEC = ARCHS["gpt3-13b"]
 
 
-def _env(scenario=None, **kw):
-    kw.setdefault("batch", 1024)
-    kw.setdefault("seq", 2048)
+def _env(scenario=None, *, batch=1024, seq=2048, mode="train",
+         decode_tokens=64, **kw):
+    if scenario is None:
+        scenario = TrainScenario(batch, seq, mode, decode_tokens)
     return CosmicEnv(spec=SPEC, n_npus=1024, device=SYSTEM_2_DEVICE,
                      scenario=scenario, **kw)
 
@@ -72,8 +73,9 @@ def _pre_refactor_evaluate(env: CosmicEnv, config: dict):
                            chunks=int(config["chunks"]),
                            sched_policy=config["sched_policy"],
                            multidim_coll=config["multidim_coll"])
-    return evaluate(env.spec, par, sys_cfg, batch=env.batch, seq=env.seq,
-                    mode=env.mode, objective=env.objective,
+    sc = env.scenario
+    return evaluate(env.spec, par, sys_cfg, batch=sc.batch, seq=sc.seq,
+                    mode=sc.mode, objective=env.objective,
                     capacity_gb=env.capacity_gb)
 
 
@@ -98,7 +100,6 @@ _PR1_GOLDEN = [
 
 def test_train_scenario_bit_identical_to_pre_refactor(clear_dse_caches):
     env = _env()
-    assert isinstance(env.scenario, TrainScenario)  # legacy ctor still works
     for i, cfg in enumerate(_sample_configs(paper_psa(1024), 25, seed=7)):
         got = env.step(cfg)
         if i < len(_PR1_GOLDEN):
@@ -398,9 +399,30 @@ def test_goodput_objective_requires_streaming_scenario():
     with pytest.raises(ValueError, match="streaming"):
         _env(TrainScenario(64, 2048, "serve"), objective="goodput")
     with pytest.raises(ValueError, match="streaming"):
-        _env(objective="goodput")  # legacy batch/seq TrainScenario path
+        _env(objective="goodput")  # the default TrainScenario
     with pytest.raises(ValueError, match="unknown objective"):
         _env(objective="not-an-objective")
+
+
+def test_env_needs_a_scenario():
+    with pytest.raises(TypeError, match="scenario"):
+        CosmicEnv(spec=SPEC, n_npus=1024, device=SYSTEM_2_DEVICE)
+
+
+@pytest.mark.parametrize("seq", [2048, None])
+def test_system_env_without_a_scenario_builds_a_train_scenario(
+        seq, clear_dse_caches):
+    """``system_env``'s batch/seq fallback is the same env as one handed
+    the ``TrainScenario`` outright: same store signature, same rewards."""
+    from repro.core.systems import system_env
+
+    built = system_env("gpt3-13b", "system2", batch=64, seq=seq)
+    given = _env(TrainScenario(64, seq or SPEC.max_seq))
+    assert built._store_sig() == given._store_sig()
+    cfgs = _sample_configs(paper_psa(1024), 8, seed=5)
+    got, want = built.step_batch(cfgs), given.step_batch(cfgs)
+    assert [(e.reward, e.latency_ms, e.valid) for e in got] == \
+        [(e.reward, e.latency_ms, e.valid) for e in want]
 
 
 def test_pipelined_multiwave_beats_analytic_composition(clear_dse_caches):

@@ -18,6 +18,7 @@ from repro.core.compute import SYSTEM_1_DEVICE, SYSTEM_2_DEVICE
 from repro.core.dse import run_search
 from repro.core.env import CosmicEnv
 from repro.core.psa import paper_psa
+from repro.core.scenario import TrainScenario
 from repro.core.workload import Parallelism
 from repro.models import model as M
 
@@ -36,7 +37,7 @@ def test_full_stack_beats_single_stack():
 
     def search(ps, seed):
         env = CosmicEnv(spec=ARCHS["gpt3-13b"], n_npus=1024, device=SYSTEM_2_DEVICE,
-                        batch=1024, seq=2048)
+                        scenario=TrainScenario(1024, 2048))
         return run_search(ps, env, "ga", steps=250, seed=seed).best_reward
 
     full = max(search(pset, s) for s in (0, 1))
@@ -58,7 +59,8 @@ def test_agents_find_multiple_distinct_optima():
     """Fig. 9: different agents converge to distinct configs of similar
     quality (design-space redundancy)."""
     env_fn = lambda: CosmicEnv(spec=ARCHS["gpt3-13b"], n_npus=1024,
-                               device=SYSTEM_2_DEVICE, batch=1024, seq=2048)
+                               device=SYSTEM_2_DEVICE,
+                               scenario=TrainScenario(1024, 2048))
     pset = paper_psa(1024)
     results = {k: run_search(pset, env_fn(), k, steps=150, seed=3) for k in ("ga", "aco")}
     rewards = [r.best_reward for r in results.values()]
