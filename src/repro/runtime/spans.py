@@ -55,6 +55,9 @@ COUNTERS: dict[str, str] = {
     "repro.engine.points": "repro.engine.generation",   # points handed in
     "repro.engine.evaluated": "repro.engine.generation",  # evaluation memo misses
     "repro.engine.copy_back_bytes": "repro.engine.generation",  # device to host
+    # ops the scheduling sweep ran over, and those the Pallas kernel swept
+    "repro.engine.sweep_ops": "repro.engine.generation",
+    "repro.engine.sweep_kernel_ops": "repro.engine.generation",
     # bytes the compiled step's collectives move (a step over a mesh)
     "repro.train.collective_bytes": "repro.train.step",
     # of those, the all-to-alls' bytes

@@ -91,14 +91,9 @@ def test_ssd_scan_mamba2_130m_width(one_chip):
     _assert_kernel(fn.lower(x, dt, a, b, b).compile())
 
 
-def test_fused_eval_request_stream_x64(one_chip):
-    """The fused jax backend's whole compiled call (f64 collective and
-    roofline pricing, the scheduling sweep, and the makespan, busy and
-    marked finish rows reduced on the device) on a 48-request stream plan
-    with a 32-point population."""
-    from repro.core.backends.jax_backend import (_busy_chunks, _fused_eval,
-                                                 _plan_parents)
-    from repro.core.workload import wave_mark_uids
+def _stream_plan():
+    """A 48-request stream trace's plan and a 32-point population's
+    duration tables."""
     from repro.core.scenario import RequestStreamScenario
     from repro.core.simulator import plan_duration_tables
     from repro.core.systems import system_env
@@ -124,12 +119,54 @@ def test_fused_eval_request_stream_x64(one_chip):
              for c in env.scenario.sim_job(env.context(cfg)).calls]
     trace = calls[0].trace
     assert all(c.trace is trace for c in calls)
-    plan, tables = plan_duration_tables(trace, calls)
+    return trace, *plan_duration_tables(trace, calls)
+
+
+def _compile_fused(one_chip, sweep):
+    from repro.core.backends.jax_backend import (_busy_chunks, _fused_eval,
+                                                 _plan_parents, _ring_parents)
+    from repro.core.workload import wave_mark_uids
+
+    trace, plan, tables = _stream_plan()
+    parents = _plan_parents(trace, plan)
     with jax.enable_x64(True):
         tabs = {k: _sds(np.shape(v), np.asarray(v).dtype, one_chip)
                 for k, v in tables.items()}
-        statics = (_plan_parents(trace, plan), *_busy_chunks(plan),
-                   wave_mark_uids(trace).astype(np.int32))
-        compiled = _fused_eval(plan, False).lower(
+        statics = (parents if sweep is None else _ring_parents(parents, sweep),
+                   *_busy_chunks(plan), wave_mark_uids(trace).astype(np.int32))
+        return _fused_eval(plan, False, sweep).lower(
             tabs, *(_sds(a.shape, a.dtype, one_chip) for a in statics)).compile()
+
+
+def test_fused_eval_request_stream_x64(one_chip):
+    """The fused jax backend's whole compiled call (f64 collective and
+    roofline pricing, the scheduling sweep, and the makespan, busy and
+    marked finish rows reduced on the device) on a 48-request stream plan
+    with a 32-point population."""
+    compiled = _compile_fused(one_chip, None)
+    assert compiled.memory_analysis() is not None
+
+
+def test_fused_eval_request_stream_sweep_kernel(one_chip):
+    """The same call with the sweep as the Pallas kernel, as a TPU runs it."""
+    from repro.core.backends.jax_backend import sweep_kernel_fits, sweep_layout
+
+    layout = sweep_layout(4096, 3, 233)
+    assert sweep_kernel_fits("tpu", layout, 32)
+    _assert_kernel(_compile_fused(one_chip, layout))
+
+
+def test_sweep_kernel_stream_cell_width(one_chip):
+    """The kernel alone at the stream cell's size: 25,872 ops, 32 members,
+    parents up to 233 ops back."""
+    from repro.core.backends.jax_backend import _sweep_pairs, sweep_layout
+
+    layout = sweep_layout(25_872, 3, 233)
+    n_pad = -(-25_872 // layout.block) * layout.block
+    with jax.enable_x64(True):
+        dur = _sds((25_872, 32), jnp.float64, one_chip)
+        ring = _sds((n_pad * layout.stride,), jnp.int32, one_chip)
+        fn = jax.jit(lambda d, r: _sweep_pairs(d, r, layout))
+        compiled = fn.lower(dur, ring).compile()
+    _assert_kernel(compiled)
     assert compiled.memory_analysis() is not None
